@@ -31,7 +31,9 @@ ARGS = ["--query", "triangle", "--nv", "80", "--ne", "800",
 
 
 def _run(extra):
-    env = dict(os.environ, PYTHONPATH=SRC)
+    # the child fakes its workers as host CPU devices; pinning it to the
+    # CPU keeps it off the chip this (parent) process may already hold
+    env = dict(os.environ, PYTHONPATH=SRC, JAX_PLATFORMS="cpu")
     out = subprocess.run(
         [sys.executable, "-m", "repro.core._delta_dist_check", *ARGS,
          *extra], capture_output=True, text=True, timeout=1800, env=env)

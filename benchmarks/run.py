@@ -2,6 +2,12 @@
 
 ``PYTHONPATH=src python -m benchmarks.run [--only fig4,...]``
 prints ``table,name,us_per_call,derived`` CSV rows.
+
+All groups run in this one process.  The groups that fake w workers
+(``fig5``, ``delta_stream``) do so in child processes pinned to the CPU
+backend (``JAX_PLATFORMS=cpu``), so no child ever needs a chip this process
+holds.  These groups measure CPU-backend or interpret-mode behaviour; none
+of their numbers is a chip measurement.
 """
 import argparse
 import sys

@@ -16,7 +16,9 @@ SRC = os.path.join(os.path.dirname(__file__), "..", "src")
 
 
 def run_cfg(workers, ne, nv, query="triangle", batch=1024):
-    env = dict(os.environ, PYTHONPATH=SRC)
+    # the child fakes its workers as host CPU devices; pinning it to the
+    # CPU keeps it off the chip this (parent) process may already hold
+    env = dict(os.environ, PYTHONPATH=SRC, JAX_PLATFORMS="cpu")
     out = subprocess.run(
         [sys.executable, "-m", "repro.core._dist_check",
          "--workers", str(workers), "--query", query, "--ne", str(ne),

@@ -30,7 +30,7 @@ import numpy as np
 from repro.core import compilestats
 from repro.core import delta as _delta
 from repro.core.bigjoin import BigJoinConfig, run_bigjoin
-from repro.core.csr import pow2_capacity
+from repro.core.csr import pow2_capacity, unique_rows
 from repro.core.plan import Plan, make_plan
 from repro.core.query import Query, fractional_edge_cover, query_by_name
 from repro.api.dsl import parse_pattern
@@ -116,7 +116,7 @@ class EpochResult:
         if self.is_noop:
             return live
         kept = _delta._diff_rows(live, self.dels)
-        return np.unique(np.concatenate([kept, self.ins]), axis=0)
+        return unique_rows(np.concatenate([kept, self.ins]))
 
 
 class QueryHandle:
@@ -226,7 +226,8 @@ class GraphSession:
                 [mesh.shape[a] for a in mesh.axis_names]))
         self.store = _delta.RegionStore(
             initial_edges, shard_w=0 if self.local else self.w,
-            compact_ratio=compact_ratio, device_resident=device_resident)
+            mesh=self.mesh, compact_ratio=compact_ratio,
+            device_resident=device_resident)
         self.handles: Dict[str, QueryHandle] = {}
         self.epoch = 0
         self._static_plans: Dict[Query, Plan] = {}
@@ -288,7 +289,7 @@ class GraphSession:
         ``EpochResult.compile_events == 0`` until a relation's base region
         outgrows its pow2 rung (amortized-rare; that one epoch re-walks a
         warm-cached ladder).  With the persistent compilation cache
-        (``REPRO_COMPILE_CACHE``) a restarted process pays deserialization,
+        (on by default, see ``compilestats``) a restarted process pays deserialization,
         not XLA, for the same ladder.  Returns compile events spent (also
         surfaced as ``StoreStats.prewarm_compiles``)."""
         snap = compilestats.snapshot()

@@ -1,32 +1,49 @@
-"""Version-tolerant aliases for JAX APIs that moved between releases.
+"""One spelling for the JAX APIs the repo uses in more than one place.
 
-The repo targets the newest stable JAX spelling (``jax.shard_map``,
-``jax.tree.flatten_with_path``) but must run on older runtimes where those
-live under ``jax.experimental.shard_map`` / ``jax.tree_util``.  Importing
-through this module keeps call sites on one spelling and confines the
-feature detection to a single place.
+Written against the installed JAX (0.9): ``jax.shard_map`` with its
+``check_vma`` flag, and the jaxpr classes under ``jax.extend.core`` (the
+``jax.core`` aliases are gone).
 """
 from __future__ import annotations
 
-import jax
+from typing import Iterator
 
-if hasattr(jax, "shard_map"):  # jax >= 0.6 spelling
-    _shard_map_impl = jax.shard_map
-    _REP_KW = "check_vma"
-else:  # pragma: no cover - exercised only on old runtimes
-    from jax.experimental.shard_map import shard_map as _shard_map_impl
-    _REP_KW = "check_rep"
+import jax
+from jax.extend import core as jex_core
+
+tree_flatten_with_path = jax.tree.flatten_with_path
 
 
 def shard_map(f, mesh, in_specs, out_specs, check_vma: bool = True):
-    """``jax.shard_map`` with the replication-check kwarg renamed as needed
-    (``check_vma`` in new JAX, ``check_rep`` before the move out of
-    ``jax.experimental``)."""
-    return _shard_map_impl(f, mesh=mesh, in_specs=in_specs,
-                           out_specs=out_specs, **{_REP_KW: check_vma})
+    """``jax.shard_map`` with keyword-only mesh/specs spelled positionally."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma)
 
 
-if hasattr(jax.tree, "flatten_with_path"):
-    tree_flatten_with_path = jax.tree.flatten_with_path
-else:  # pragma: no cover
-    tree_flatten_with_path = jax.tree_util.tree_flatten_with_path
+def subjaxprs(v) -> Iterator[jex_core.Jaxpr]:
+    """The jaxprs held by one equation parameter (pjit bodies, control-flow
+    branches and bodies, kernel bodies), looking inside tuples/lists."""
+    if isinstance(v, jex_core.ClosedJaxpr):
+        yield v.jaxpr
+    elif isinstance(v, jex_core.Jaxpr):
+        yield v
+    elif isinstance(v, (tuple, list)):
+        for x in v:
+            yield from subjaxprs(x)
+
+
+def iter_eqns(jaxpr) -> Iterator:
+    """Every equation of ``jaxpr`` (a Jaxpr or ClosedJaxpr) and of all the
+    jaxprs nested in its parameters, depth first."""
+    if isinstance(jaxpr, jex_core.ClosedJaxpr):
+        jaxpr = jaxpr.jaxpr
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for v in eqn.params.values():
+            for sub in subjaxprs(v):
+                yield from iter_eqns(sub)
+
+
+def primitive_names(jaxpr) -> set:
+    """Names of every primitive in ``jaxpr``, nested jaxprs included."""
+    return {eqn.primitive.name for eqn in iter_eqns(jaxpr)}

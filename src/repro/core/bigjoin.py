@@ -31,6 +31,7 @@ from repro.core import compilestats
 from repro.core.dataflow_index import VersionedIndex
 from repro.core.plan import Plan
 from repro.errors import (CapacityOverflow, OVF_OUT, OVF_QUEUE, OVF_SEED)
+from repro.kernels import on_default_path
 
 Indices = Dict[str, VersionedIndex]
 
@@ -39,18 +40,19 @@ Indices = Dict[str, VersionedIndex]
 class BigJoinConfig:
     """``batch`` is B' — the per-step proposal budget (§3.1.2).
 
-    ``use_kernel`` (default on) routes each level's extension step through
-    the fused Pallas pipeline (kernels/extend) and membership probes through
-    the multi-region intersect kernel; ``kernel_interpret`` overrides the
-    platform gating (None = compiled on TPU, interpret elsewhere).  The
-    jnp path (``use_kernel=False``) remains as oracle and fallback.
+    ``use_kernel`` (default on) routes membership probes through the
+    multi-region intersect kernel, and each level's extension step through
+    the fused Pallas pipeline (kernels/extend) when that family is on the
+    default path (``repro.kernels.on_default_path``); ``kernel_interpret``
+    overrides the platform gating (None = compiled on TPU, interpret
+    elsewhere).  The jnp path (``use_kernel=False``) remains as oracle.
     """
 
     batch: int = 4096
     seed_chunk: int = 4096
     out_capacity: int = 1 << 20
     mode: str = "collect"  # "collect" | "count"
-    use_kernel: bool = True  # fused Pallas extension step + member kernels
+    use_kernel: bool = True  # member kernel (+ fused extend when on path)
     kernel_interpret: Optional[bool] = None  # None: platform detection
 
     def queue_capacity(self) -> int:
@@ -171,10 +173,12 @@ def _scatter_append(dst: jax.Array, size: jax.Array, src: jax.Array,
 def _level_branch(plan: Plan, cfg: BigJoinConfig, li: int):
     """Build the pop→count-min→propose→intersect→push branch for level li.
 
-    With ``cfg.use_kernel`` the count-min/propose/intersect middle runs as
-    ONE fused ``pallas_call`` (kernels/extend): proposals are born, gathered
-    and membership-filtered in VMEM without HBM round-trips between stages.
-    The jnp stage sequence below is the bit-exact oracle and fallback.
+    With ``cfg.use_kernel`` and the extend family on the default path, the
+    count-min/propose/intersect middle runs as ONE fused ``pallas_call``
+    (kernels/extend): proposals are born, gathered and membership-filtered
+    in VMEM without HBM round-trips between stages.  Otherwise the jnp
+    stage sequence below runs (the bit-exact oracle), its intersections
+    through one multi-region member kernel launch per binding.
     """
     lv = plan.levels[li]
     m = plan.query.num_attrs
@@ -243,10 +247,9 @@ def _level_branch(plan: Plan, cfg: BigJoinConfig, li: int):
             pos = [list(new_bound).index(a) for a in b.key_attrs]
             qk = _pack_cols(new_prefix, pos, idx.pos[0].key.dtype)
             is_min = min_i[r] == bi
-            ok = jnp.where(
-                is_min,
-                ~idx.deleted(qk, cand),
-                idx.member(qk, cand))
+            mem, dele = idx.signed_member(qk, cand, cfg.use_kernel,
+                                          cfg.kernel_interpret)
+            ok = jnp.where(is_min, ~dele, mem)
             n_isect = n_isect + (alive & ~is_min).sum().astype(jnp.int64)
             alive = alive & ok
         return cand, r, alive, allowed, consumed, n_proposed, n_isect
@@ -258,7 +261,7 @@ def _level_branch(plan: Plan, cfg: BigJoinConfig, li: int):
         wweight = qu.weight[:W]
         valid = jnp.arange(W, dtype=jnp.int32) < qu.size
 
-        use_fused = cfg.use_kernel
+        use_fused = cfg.use_kernel and on_default_path("extend")
         if use_fused:
             from repro.kernels.intersect.ops import (default_interpret,
                                                      fused_fits)

@@ -12,26 +12,34 @@ to `merge_index`/`_commit_fold`/`_compact_fold`/dataflow steps.  ``StoreStats``
 and ``EpochResult`` surface :func:`total` snapshots so tests and benchmarks
 can assert "zero recompiles after warmup" instead of eyeballing medians.
 
-**Persistent cache.**  :func:`enable_persistent_cache` wires
-``jax.experimental.compilation_cache`` so a restarted worker or CI run
-deserializes XLA executables instead of recompiling them.  It must run
-BEFORE the first jit use of the process; importing :mod:`repro.core.delta`
-(or any api module) is early enough because that import triggers this
-module, which auto-enables when ``REPRO_COMPILE_CACHE`` is set to a
-directory path.
+**Persistent cache.**  :func:`enable_persistent_cache` turns on JAX's
+persistent compilation cache so a restarted worker or CI run deserializes
+XLA executables instead of recompiling them.  The directory is placed from
+outside: ``JAX_COMPILATION_CACHE_DIR`` when it is set (JAX reads it itself;
+no other directory is set in code), else the fixed in-checkout
+``<repo>/.jax_cache`` — a path that never moves, since the path is part of
+what a later process must find again.  Importing ``repro`` enables it,
+before anything is compiled.
 """
 from __future__ import annotations
 
 import os
+import pathlib
 import threading
-from typing import Dict, Optional
+from typing import Dict, Mapping, Optional
 
 _LOCK = threading.Lock()
 _COUNTS: Dict[str, int] = {}
 _PERSISTENT_HITS = [0]
 _CACHE_DIR: Optional[str] = None
 
-ENV_VAR = "REPRO_COMPILE_CACHE"
+ENV_VAR = "JAX_COMPILATION_CACHE_DIR"
+CHECKOUT_CACHE = str(pathlib.Path(__file__).resolve().parents[3]
+                     / ".jax_cache")
+# LRU bound of the cache; a bound also makes JAX serialize every read and
+# write of the directory behind a file lock, so concurrent processes (test
+# workers, a pool's replicas) never read an entry another one is writing
+CACHE_MAX_BYTES = 4 << 30
 
 
 def record(name: str) -> None:
@@ -81,46 +89,41 @@ def cache_dir() -> Optional[str]:
     return _CACHE_DIR
 
 
-def enable_persistent_cache(path: Optional[str] = None) -> Optional[str]:
-    """Point jax at a persistent on-disk compilation cache.  Idempotent.
+def cache_dir_for(environ: Mapping[str, str]) -> str:
+    """Where the cache goes under ``environ``: ``JAX_COMPILATION_CACHE_DIR``
+    if set, else the fixed in-checkout path."""
+    return environ.get(ENV_VAR) or CHECKOUT_CACHE
 
-    ``path`` defaults to ``$REPRO_COMPILE_CACHE``; returns the directory in
-    use, or None when no path is configured.  Must run before the process's
-    first jit execution — later calls still help future compilations but
-    cannot recover ones already done.  The thresholds are zeroed so even
-    sub-second CPU kernels (our folds) persist; jax's own default would skip
-    anything compiling in < 1s, which on the CPU CI lane is everything.
-    """
+
+def enable_persistent_cache() -> str:
+    """Turn on the persistent compilation cache (idempotent); returns its
+    directory.  Must run before the process's first jit execution — later
+    calls still help future compilations but cannot recover ones already
+    done.  The thresholds are zeroed so even sub-second CPU kernels (our
+    folds) persist; jax's own default would skip anything compiling in
+    < 1s, which on the CPU CI lane is everything."""
     global _CACHE_DIR
-    path = path or os.environ.get(ENV_VAR) or None
-    if not path:
-        return None
-    if _CACHE_DIR == path:
+    if _CACHE_DIR is not None:
         return _CACHE_DIR
     import jax
+    from jax import monitoring
 
-    os.makedirs(path, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", path)
+    path = cache_dir_for(os.environ)
+    if ENV_VAR not in os.environ:
+        jax.config.update("jax_compilation_cache_dir", path)
+    jax.config.update("jax_compilation_cache_max_size", CACHE_MAX_BYTES)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-    from jax.experimental.compilation_cache import compilation_cache as cc
-    cc.set_cache_dir(path)
-    if _CACHE_DIR is None:  # register the hit listener once
-        try:
-            from jax import monitoring
 
-            def _listener(event: str, **kw):
-                if "cache_hit" in event:
-                    _PERSISTENT_HITS[0] += 1
+    def _listener(event: str, **kw):
+        if "cache_hit" in event:
+            _PERSISTENT_HITS[0] += 1
 
-            monitoring.register_event_listener(_listener)
-        except Exception:  # pragma: no cover - older jax without monitoring
-            pass
+    monitoring.register_event_listener(_listener)
     _CACHE_DIR = path
     return _CACHE_DIR
 
 
-# env knob: the earliest import of this module (delta/session import it
-# before building anything jitted) switches the cache on for the process
-if os.environ.get(ENV_VAR):  # pragma: no cover - exercised via subprocess
-    enable_persistent_cache()
+# ``repro/__init__`` imports this module, so the cache is on before the
+# process compiles anything of the package
+enable_persistent_cache()

@@ -41,6 +41,42 @@ SENTINEL32 = np.int32(2**31 - 1)
 # [cap/SEG, SEG] view is a free reshape (no pad/concat per probe).
 SEG = 128
 
+# The mesh axis the distributed engines shard worker stacks over.
+AXIS = "workers"
+
+
+def worker_sharding(mesh, spec=None):
+    """``NamedSharding(mesh, spec)`` on the mesh the engines' shard_map
+    programs run on (``GraphSession``/``SessionPool`` own it), or None
+    without a mesh (a store that vmaps its w shards on one device keeps
+    the default placement).  ``spec`` defaults to the leading worker axis
+    split over all mesh axes, one worker row per device."""
+    if mesh is None:
+        return None
+    from jax.sharding import NamedSharding, PartitionSpec
+    if spec is None:
+        names = tuple(mesh.axis_names)
+        spec = PartitionSpec(names[0] if len(names) == 1 else names)
+    return NamedSharding(mesh, spec)
+
+
+def place_worker_stack(x: np.ndarray, mesh=None) -> jax.Array:
+    """Upload a [w, ...] per-worker stack with one shard per device of
+    ``mesh``, so the engines' shard_map programs and folds take it where
+    it lies; default placement without a mesh."""
+    sharding = worker_sharding(mesh)
+    return jnp.asarray(x) if sharding is None else jax.device_put(x, sharding)
+
+
+def place_for_workers(x: np.ndarray, mesh=None) -> jax.Array:
+    """Upload a delta-sized host array for use with a sharded store:
+    replicated on the store's ``mesh`` (an explicit host-to-device copy,
+    never a device-to-device one inside a fold), default placement
+    without a mesh."""
+    from jax.sharding import PartitionSpec
+    sharding = worker_sharding(mesh, PartitionSpec())
+    return jnp.asarray(x) if sharding is None else jax.device_put(x, sharding)
+
 
 def round_capacity(cap: int) -> int:
     return -(-max(int(cap), 1) // SEG) * SEG
@@ -143,6 +179,31 @@ def unpack_key(packed: PackedKey, num_cols: int) -> np.ndarray:
     return np.stack(cols, 1)
 
 
+def unique_rows(rows: np.ndarray) -> np.ndarray:
+    """``np.unique(rows, axis=0)`` for signed integer rows: the same rows in
+    the same row-lex order, an order of magnitude faster at graph scale.
+
+    Two columns that fit int32 sort as ONE packed int64 word (signed high
+    word, low word offset by 2^31 so it orders as unsigned); wider rows use
+    one lexsort plus an adjacent-difference mask — never the structured
+    dtype sort ``np.unique(axis=0)`` runs."""
+    rows = np.asarray(rows)
+    if (rows.ndim != 2 or rows.shape[0] < 2
+            or not np.issubdtype(rows.dtype, np.signedinteger)):
+        return np.unique(rows, axis=0)
+    i32 = np.iinfo(np.int32)
+    if rows.shape[1] == 2 and (rows.dtype.itemsize <= 4 or (
+            rows.min() >= i32.min and rows.max() <= i32.max)):
+        a = rows.astype(np.int64)
+        packed = np.unique((a[:, 0] << 32) | (a[:, 1] + 2**31))
+        return np.stack([packed >> 32, (packed & 0xFFFFFFFF) - 2**31],
+                        axis=1).astype(rows.dtype)
+    s = rows[np.lexsort(rows.T[::-1])]
+    keep = np.ones(s.shape[0], bool)
+    keep[1:] = (s[1:] != s[:-1]).any(axis=1)
+    return s[keep]
+
+
 def single_word_hi(num_key_cols: int) -> bool:
     """True when the packed hi word holds at most ONE bound column, i.e. a
     single int32 id — the precondition for the narrow (int32) key dtype.
@@ -170,11 +231,11 @@ def build_index(tuples: np.ndarray, key_pos: Tuple[int, ...], ext_pos: int,
         if key_pos else np.zeros(tuples.shape[0], np.int64)
     val = tuples[:, ext_pos].astype(np.int32)
     if isinstance(key, tuple):  # composite (hi, lo) key: 3-4 bound columns
-        kvl = np.unique(np.stack([key[0], key[1], val.astype(np.int64)],
-                                 axis=1), axis=0)
+        kvl = unique_rows(np.stack([key[0], key[1], val.astype(np.int64)],
+                                   axis=1))
         key, lo, val = kvl[:, 0], kvl[:, 1], kvl[:, 2].astype(np.int32)
     else:
-        kv = np.unique(np.stack([key, val.astype(np.int64)], axis=1), axis=0)
+        kv = unique_rows(np.stack([key, val.astype(np.int64)], axis=1))
         key, lo, val = kv[:, 0], None, kv[:, 1].astype(np.int32)
     n = key.shape[0]
     cap = round_capacity(max(int(capacity or n), n, 1))
@@ -257,7 +318,7 @@ def capacity_ladder(lo: int, hi: int) -> list:
 def build_sharded_index(tuples: np.ndarray, key_pos: Tuple[int, ...],
                         ext_pos: int, num_shards: int,
                         capacity: int | None = None,
-                        narrow: bool | None = None) -> IndexData:
+                        narrow: bool | None = None, mesh=None) -> IndexData:
     """Hash-partition one extension index over ``num_shards`` workers.
 
     Returns an IndexData whose arrays carry a leading [w] worker axis
@@ -270,7 +331,8 @@ def build_sharded_index(tuples: np.ndarray, key_pos: Tuple[int, ...],
     a SEG-aligned power of two of the largest shard, so shapes stay stable
     across update batches and the jit cache stays warm.  ``capacity`` is a
     per-shard floor.  Key narrowness (int32 vs int64) is decided globally so
-    every shard row has one dtype and one sentinel.
+    every shard row has one dtype and one sentinel.  ``mesh`` places the
+    stacks one worker row per device (:func:`place_worker_stack`).
     """
     tuples = np.asarray(tuples)
     if tuples.ndim != 2:
@@ -280,12 +342,12 @@ def build_sharded_index(tuples: np.ndarray, key_pos: Tuple[int, ...],
         if key_pos else np.zeros(tuples.shape[0], np.int64)
     val = tuples[:, ext_pos].astype(np.int32)
     if isinstance(key, tuple):  # composite: ownership by the combined word
-        kvl = np.unique(np.stack([key[0], key[1], val.astype(np.int64)],
-                                 axis=1), axis=0)
+        kvl = unique_rows(np.stack([key[0], key[1], val.astype(np.int64)],
+                                   axis=1))
         key, klo, val = kvl[:, 0], kvl[:, 1], kvl[:, 2].astype(np.int32)
         own = shard_of((key, klo), w)
     else:
-        kv = np.unique(np.stack([key, val.astype(np.int64)], axis=1), axis=0)
+        kv = unique_rows(np.stack([key, val.astype(np.int64)], axis=1))
         key, klo, val = kv[:, 0], None, kv[:, 1].astype(np.int32)
         own = shard_of(key, w)
     counts = np.bincount(own, minlength=w).astype(np.int64)
@@ -311,9 +373,11 @@ def build_sharded_index(tuples: np.ndarray, key_pos: Tuple[int, ...],
         out_v[i, :hi - lo] = sv[lo:hi]
         if out_lo is not None:
             out_lo[i, :hi - lo] = sl[lo:hi]
-    return IndexData(jnp.asarray(out_k), jnp.asarray(out_v),
-                     jnp.asarray(counts.astype(np.int32)),
-                     None if out_lo is None else jnp.asarray(out_lo))
+    def put(x):
+        return place_worker_stack(x, mesh)
+
+    return IndexData(put(out_k), put(out_v), put(counts.astype(np.int32)),
+                     None if out_lo is None else put(out_lo))
 
 
 def empty_index(capacity: int = 1, narrow: bool = True,
@@ -587,7 +651,7 @@ class Graph:
                    dedup: bool = True) -> "Graph":
         edges = np.asarray(edges, np.int32).reshape(-1, 2)
         if dedup and edges.size:
-            edges = np.unique(edges, axis=0)
+            edges = unique_rows(edges)
         nv = int(num_vertices if num_vertices is not None
                  else (edges.max() + 1 if edges.size else 0))
         return cls(edges, nv)

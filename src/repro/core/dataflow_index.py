@@ -98,12 +98,11 @@ class VersionedIndex:
         return val
 
     @staticmethod
-    def _kernel_ok(interpret, regions) -> bool:
-        from repro.kernels.intersect.ops import default_interpret, fused_fits
+    def _kernel_ok(regions) -> bool:
+        """Mixed 1-word/2-word regions never share a launch; any size does
+        (the member kernel keeps the index in HBM)."""
         composite = [r.lo is not None for r in regions]
-        if any(composite) and not all(composite):
-            return False  # mixed 1-word/2-word regions never share a launch
-        return default_interpret(interpret) or fused_fits(regions)
+        return all(composite) or not any(composite)
 
     def signed_member(self, qkey: jax.Array, qval: jax.Array,
                       use_kernel: bool = False,
@@ -113,11 +112,9 @@ class VersionedIndex:
         With ``use_kernel`` this is a single fused ``pallas_call`` across
         every positive and negative region (R launches collapse to 1) —
         composite regions included, with ``qkey`` the (hi, lo) int64 probe
-        pair; the jnp path mirrors the same signed-weight reduction.  A
-        compiled (non-interpret) call whose regions exceed the VMEM budget
-        falls back to the jnp path rather than failing Mosaic compilation.
+        pair; the jnp path mirrors the same signed-weight reduction.
         """
-        if use_kernel and self._kernel_ok(interpret, self.pos + self.neg):
+        if use_kernel and self._kernel_ok(self.pos + self.neg):
             from repro.kernels.intersect.ops import signed_member
             wpos, wneg = signed_member(self.pos, self.neg, qkey, qval,
                                        interpret=interpret)
@@ -142,7 +139,7 @@ class VersionedIndex:
         shape = qkey[0].shape if isinstance(qkey, tuple) else qkey.shape
         if not self.neg:
             return jnp.zeros(shape, bool)
-        if use_kernel and self._kernel_ok(interpret, self.neg):
+        if use_kernel and self._kernel_ok(self.neg):
             from repro.kernels.intersect.ops import signed_member
             _, wneg = signed_member((), self.neg, qkey, qval,
                                     interpret=interpret)

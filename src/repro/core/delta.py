@@ -52,7 +52,6 @@ import dataclasses
 import functools
 import itertools
 import os
-import warnings
 from typing import Dict, List, Optional, Tuple
 
 import jax
@@ -70,6 +69,7 @@ from repro.core.plan import Plan, make_delta_plan
 from repro.core.query import Query, delta_queries
 from repro.errors import (CapacityOverflow, ESCALATES_BATCH, ESCALATES_OUT,
                           SnapshotError)
+from repro.kernels import on_default_path
 
 Projection = Tuple[str, Tuple[int, ...], int]  # (rel, key_pos, ext_pos)
 
@@ -82,24 +82,21 @@ Projection = Tuple[str, Tuple[int, ...], int]  # (rel, key_pos, ext_pos)
 STRICT_TRANSFERS = os.environ.get("REPRO_STRICT_TRANSFERS", "") not in ("",
                                                                         "0")
 
-# Merge/fold kernel routing for the commit path: None = ON everywhere
-# (compiled Pallas on TPU, interpret-mode Pallas — i.e. the same kernel
-# body lowered through XLA — on CPU, matching the intersect/extend ops);
-# True/False force.  REPRO_MERGE_KERNEL=0 disables from the environment.
-# The commit fold takes the single-launch fused kernel (kernels/merge/fold)
-# when its operands fit the VMEM budget, sharded meshes included (the
-# kernel grids over the worker axis); the compaction fold keeps the
-# rank-kernel-per-op chain, jnp when sharded (vmap-of-pallas is not a
-# supported production path).
+# Merge/fold kernel routing for the commit path: None = the static choice of
+# ``repro.kernels.on_default_path`` (the fused commit fold and the rank
+# kernel are off it: neither compiles for TPU), True/False force — tests
+# force True to exercise the kernels in interpret mode.  With the kernels
+# forced on, the commit fold takes the single-launch fused kernel
+# (kernels/merge/fold) when its operands fit the VMEM budget, sharded meshes
+# included (the kernel grids over the worker axis); the compaction fold
+# keeps the rank-kernel-per-op chain, jnp when sharded (vmap-of-pallas is
+# not a supported production path).
 USE_MERGE_KERNEL: Optional[bool] = None
 
 
 def _merge_kernel_on() -> bool:
     if USE_MERGE_KERNEL is None:
-        env = os.environ.get("REPRO_MERGE_KERNEL", "")
-        if env != "":
-            return env not in ("0", "false", "off")
-        return True
+        return on_default_path("fold") and on_default_path("rank")
     return bool(USE_MERGE_KERNEL)
 
 
@@ -282,25 +279,6 @@ def _normalize_core(p_hi: jax.Array, p_lo: jax.Array, w: jax.Array,
     return oih, oil, ni, odh, odl, nd
 
 
-# donation-mismatch advisories are expected on rung-growth epochs (the
-# donated cins/cdel at rung r cannot alias outputs at the next rung) and
-# during prewarm's cross-rung walk; steady state the shapes match and the
-# donation holds — silence the per-signature lowering warning
-warnings.filterwarnings(
-    "ignore", message="Some donated buffers were not usable")
-
-
-# Committed-region donation is disabled whenever the persistent
-# compilation cache is active: executables deserialized from the on-disk
-# cache mis-handle the in-place aliasing (observed on the CPU mesh path
-# as corrupted committed regions — compaction-count assertion failures —
-# in an otherwise bit-identical run that passes when the same fold is
-# compiled fresh).  Donation only saves one committed-region generation
-# of memory per epoch, and the donation config is part of the executable
-# fingerprint, so the two variants never collide in the cache.
-_COMMIT_DONATE = () if os.environ.get(compilestats.ENV_VAR) else (1, 2)
-
-
 def _commit_fold_impl(base: IndexData, cins: IndexData, cdel: IndexData,
                       uins: IndexData, udel: IndexData, *, cins_cap: int,
                       cdel_cap: int, sharded: bool, use_kernel: bool = False):
@@ -314,17 +292,13 @@ def _commit_fold_impl(base: IndexData, cins: IndexData, cdel: IndexData,
     leading worker axis: ownership is by packed key, so every merge is
     shard-local and the distributed commit stays collective-free.
 
-    The committed inputs (``cins``/``cdel``) are DONATED: commit replaces
-    both with the fold outputs immediately, and steady state (no rung
-    growth) the output capacities equal the input capacities, so XLA
-    aliases the buffers in place of allocating a second committed-region
-    generation — the serving pipeline's epoch k commit never doubles
-    committed memory while batch k+1 is being prepared (DESIGN.md §9).
-    ``base`` passes through untouched and is never donated; the staged
-    delta regions stay undonated too (their pinned delta capacity can
-    never alias a committed-rung output).  Exception: with the persistent
-    compilation cache enabled donation is switched off entirely — see
-    ``_COMMIT_DONATE`` above.
+    No input is donated: the old committed regions survive the fold, so
+    a commit that fails midway rolls back to them (DESIGN.md §10).  The
+    price is one committed-region generation of peak memory per epoch;
+    donating ``cins``/``cdel`` would save it, but executables read back
+    from the persistent compilation cache (always on, `compilestats`)
+    mis-handled the in-place aliasing on the CPU mesh path (corrupted
+    committed regions in an otherwise bit-identical run).
 
     ``use_kernel`` routes the whole fold — both outputs — through ONE
     fused ``pallas_call`` per relation (`kernels/merge/fold.py`): only the
@@ -364,15 +338,9 @@ def _commit_fold_impl(base: IndexData, cins: IndexData, cdel: IndexData,
     return fold(base, cins, cdel, uins, udel)
 
 
-_COMMIT_STATICS = ("cins_cap", "cdel_cap", "sharded", "use_kernel")
 _commit_fold = functools.partial(
-    jax.jit, static_argnames=_COMMIT_STATICS,
-    donate_argnums=_COMMIT_DONATE)(_commit_fold_impl)
-# rollback-safe variant: no donation, so the old committed regions survive
-# the fold and a mid-commit fault can roll the store back to them.
-# ``RegionStore.commit`` selects it whenever fault injection is armed.
-_commit_fold_safe = functools.partial(
-    jax.jit, static_argnames=_COMMIT_STATICS)(_commit_fold_impl)
+    jax.jit, static_argnames=("cins_cap", "cdel_cap", "sharded",
+                              "use_kernel"))(_commit_fold_impl)
 
 
 @functools.partial(jax.jit, static_argnames=("out_cap", "sharded",
@@ -403,8 +371,8 @@ def _any_member(idx: IndexData, qk: jax.Array, qv: jax.Array,
 
 
 def _packed_index(rows: np.ndarray, shard_w: int = 0,
-                  arity: int = 2, capacity: Optional[int] = None
-                  ) -> IndexData:
+                  arity: int = 2, capacity: Optional[int] = None,
+                  mesh=None) -> IndexData:
     """Packed full-row IndexData (key = the relation's lex word pair,
     val ≡ 0) from host rows — only ever built for the initial relations and
     per-epoch deltas.  Delegates to the csr builders over a zero ext column
@@ -413,38 +381,48 @@ def _packed_index(rows: np.ndarray, shard_w: int = 0,
     the cross-structure shard agreement the distributed commit folds rely
     on is not re-implemented here.  ``capacity`` (a per-shard floor when
     sharded) lets the caller pin the ratcheted rung; the pow2 of the actual
-    row count is the lower bound either way."""
+    row count is the lower bound either way.  ``mesh`` places the sharded
+    stacks."""
     rows = np.asarray(rows, np.int32).reshape(-1, arity)
     rows_ext = np.concatenate(
         [rows, np.zeros((rows.shape[0], 1), np.int32)], axis=1)
     key_pos = tuple(range(arity))
     if shard_w:
         return csr.build_sharded_index(rows_ext, key_pos, arity, shard_w,
-                                       capacity=capacity, narrow=False)
+                                       capacity=capacity, narrow=False,
+                                       mesh=mesh)
     return csr.build_index(
         rows_ext, key_pos, arity,
         capacity=max(int(capacity or 0), _pow2(rows_ext.shape[0])),
         narrow=False)
 
 
-def _empty_packed(shard_w: int = 0, arity: int = 2) -> IndexData:
+def _empty_packed(shard_w: int = 0, arity: int = 2, mesh=None
+                  ) -> IndexData:
     composite = arity > 2
     if not shard_w:
         return csr.empty_index(narrow=False, composite=composite)
     w = int(shard_w)
+
+    def put(x):
+        return csr.place_worker_stack(x, mesh)
+
     return IndexData(
-        jnp.full((w, csr.SEG), jnp.int64(csr.SENTINEL), jnp.int64),
-        jnp.zeros((w, csr.SEG), jnp.int32), jnp.zeros(w, jnp.int32),
-        jnp.full((w, csr.SEG), jnp.int64(csr.SENTINEL), jnp.int64)
+        put(np.full((w, csr.SEG), csr.SENTINEL, np.int64)),
+        put(np.zeros((w, csr.SEG), np.int32)), put(np.zeros(w, np.int32)),
+        put(np.full((w, csr.SEG), csr.SENTINEL, np.int64))
         if composite else None)
 
 
 def _pad_probe(keys, vals: np.ndarray, sent,
-               cap: Optional[int] = None) -> Tuple:
+               cap: Optional[int] = None, mesh=None) -> Tuple:
     """Pow2-pad a probe batch; ``keys`` is one packed array or a composite
     (hi, lo) pair (padding rows take the sentinel in every key word).
     ``cap`` raises the pad to a ratcheted rung so probe shapes stay pinned
-    across batches."""
+    across batches.  ``mesh``: replicate on a sharded store's mesh."""
+    def put(x):
+        return csr.place_for_workers(x, mesh)
+
     if isinstance(keys, tuple):
         hi, lo = keys
         B = max(int(cap or 0), _pow2(hi.shape[0]))
@@ -454,13 +432,13 @@ def _pad_probe(keys, vals: np.ndarray, sent,
         kl[:lo.shape[0]] = lo
         v = np.zeros(B, np.int32)
         v[:vals.shape[0]] = vals
-        return (jnp.asarray(kh), jnp.asarray(kl)), jnp.asarray(v)
+        return (put(kh), put(kl)), put(v)
     B = max(int(cap or 0), _pow2(keys.shape[0]))
     k = np.full(B, sent, keys.dtype)
     k[:keys.shape[0]] = keys
     v = np.zeros(B, np.int32)
     v[:vals.shape[0]] = vals
-    return jnp.asarray(k), jnp.asarray(v)
+    return put(k), put(v)
 
 
 def _sds_like(idx: IndexData, cap: Optional[int] = None) -> IndexData:
@@ -468,17 +446,19 @@ def _sds_like(idx: IndexData, cap: Optional[int] = None) -> IndexData:
     axis of every padded array) overridden to ``cap`` — the argument
     prototype prewarm warms a fold against (see :func:`_warm_call`).
     Mirrors dtypes, the composite ``lo`` word and the sharded leading [w]
-    axis exactly, so the AOT signature is the runtime signature."""
-    S = jax.ShapeDtypeStruct
-
-    def arr(a):
+    axis — with its mesh placement — exactly, so the AOT signature is the
+    runtime signature (jit keys its trace cache on input shardings)."""
+    def arr(a, cap=None):
         shp = list(a.shape)
         if cap is not None:
             shp[-1] = int(cap)
-        return S(tuple(shp), a.dtype)
+        sharding = getattr(a, "sharding", None)
+        if not isinstance(sharding, jax.sharding.NamedSharding):
+            sharding = None  # default placement, as uploaded
+        return jax.ShapeDtypeStruct(tuple(shp), a.dtype, sharding=sharding)
 
-    return IndexData(arr(idx.key), arr(idx.val), S(idx.n.shape, idx.n.dtype),
-                     None if idx.lo is None else arr(idx.lo))
+    return IndexData(arr(idx.key, cap), arr(idx.val, cap), arr(idx.n),
+                     None if idx.lo is None else arr(idx.lo, cap))
 
 
 def _warm_call(fn, *args, **static):
@@ -493,7 +473,8 @@ def _warm_call(fn, *args, **static):
     when the stream first crosses onto the rung, invisibly to the trace
     counters.  Zero-filled inputs make every fold a trivially-empty pass
     (all counts 0), so the execution itself costs microseconds."""
-    z = jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype), args)
+    z = jax.tree.map(
+        lambda s: jnp.zeros(s.shape, s.dtype, device=s.sharding), args)
     jax.block_until_ready(fn(*z, **static))
 
 
@@ -577,6 +558,7 @@ class _Regions:
     rel: str = "edge"
     rel_arity: int = 0  # the backing relation's TRUE arity
     shard_w: int = 0
+    mesh: object = None  # the sharded store's mesh (placement only)
     device_resident: bool = True
     narrow: bool = True
     derived: bool = False
@@ -623,7 +605,7 @@ class _Regions:
                 ratchet.capacity(key, per)
             idx = build_sharded_index(rows, self.key_pos, self.ext_pos,
                                       self.shard_w, capacity=cap,
-                                      narrow=self.narrow)
+                                      narrow=self.narrow, mesh=self.mesh)
         else:
             cap = _pow2(rows.shape[0]) if ratchet is None else \
                 ratchet.capacity(key, rows.shape[0])
@@ -725,7 +707,7 @@ class _Regions:
         cap = None if ratchet is None else \
             ratchet.capacity(rkey, ins.shape[0])
         qk, qv = _pad_probe(key, ins[:, self.ext_pos].astype(np.int32),
-                            sent, cap=cap)
+                            sent, cap=cap, mesh=self.mesh)
         return bool(_any_member(self.d_cdel, qk, qv,
                                 sharded=bool(self.shard_w)))
 
@@ -882,12 +864,20 @@ class RegionStore:
     many mesh workers (the distributed engine's layout), n-ary regions
     included — ownership is by the row's composite key, so commits stay
     owner-local and collective-free and no worker holds O(|R|) of any
-    relation.
+    relation.  ``mesh`` (shard_w devices) is the mesh the engines'
+    shard_map programs run on: the worker stacks are placed one row per
+    device of it, in its device order, and delta uploads are replicated on
+    it.  Without one, the stacks keep the default placement.
     """
 
     def __init__(self, initial, shard_w: int = 0,
-                 compact_ratio: float = 0.5, device_resident: bool = True):
+                 compact_ratio: float = 0.5, device_resident: bool = True,
+                 mesh=None):
+        if mesh is not None and mesh.size != shard_w:
+            raise ValueError(f"a {mesh.size}-device mesh cannot place a "
+                             f"store of {shard_w} shards")
         self.shard_w = shard_w
+        self.mesh = mesh
         self.compact_ratio = compact_ratio
         self.device_resident = bool(device_resident)
         self.projections: Dict[Projection, _Regions] = {}
@@ -960,17 +950,18 @@ class RegionStore:
                 f"relation {rel!r} was declared with arity {old.arity}, "
                 f"cannot re-seed with arity {ar}")
         rows, _ = _check_batch(rel, rows.reshape(-1, ar), None, ar)
-        rows = np.unique(rows, axis=0)
+        rows = csr.unique_rows(rows)
         st = _RelLive(arity=ar)
         if self.device_resident:
             # each live LSM shards like the projections (ownership by
             # packed key), so per-worker live memory stays O(|R|/w)
             st.lb = _packed_index(rows, self.shard_w, ar,
                                   capacity=self._base_cap(rel,
-                                                          rows.shape[0]))
+                                                          rows.shape[0]),
+                                  mesh=self.mesh)
             self.base_ratchet.observe(("base", rel), st.lb.key.shape[-1])
-            st.lc_ins = _empty_packed(self.shard_w, ar)
-            st.lc_del = _empty_packed(self.shard_w, ar)
+            st.lc_ins = _empty_packed(self.shard_w, ar, self.mesh)
+            st.lc_del = _empty_packed(self.shard_w, ar, self.mesh)
             zero = np.zeros(self.shard_w, np.int64) if self.shard_w else 0
             nb = _count_of(st.lb) if self.shard_w else rows.shape[0]
             st.n_live = [nb, zero, zero]  # base, cins, cdel
@@ -1131,7 +1122,7 @@ class RegionStore:
         narrow = csr.single_word_hi(len(key_pos)) and \
             (rows.size == 0 or int(rows.max()) < int(csr.SENTINEL32))
         reg = _Regions(key_pos, ext_pos, rel=rel, rel_arity=st.arity,
-                       shard_w=self.shard_w,
+                       shard_w=self.shard_w, mesh=self.mesh,
                        device_resident=self.device_resident, narrow=narrow,
                        derived=not covers, _store=self)
         empty = rows[:0]
@@ -1239,12 +1230,16 @@ class RegionStore:
         P = self.pin_delta_marks(ub)
         sharded = bool(self.shard_w)
         # statics must match the runtime call sites EXACTLY or the warm
-        # epoch recompiles: commit runs the fused fold kernel on every
-        # platform (sharded included — grid=(w,), no vmap), compaction
-        # keeps the single-host-only rank chain
+        # epoch recompiles: the commit fold's kernel choice, and the
+        # compaction's single-host-only rank chain
         commit_k = _merge_kernel_on()
         compact_k = _merge_kernel_on() and not sharded
-        S = jax.ShapeDtypeStruct
+        # delta-sized probe inputs are uploaded replicated on the store's
+        # mesh (``csr.place_for_workers``); the prototypes say so too
+        rep = csr.worker_sharding(self.mesh, jax.sharding.PartitionSpec())
+
+        def S(shape, dtype):
+            return jax.ShapeDtypeStruct(shape, dtype, sharding=rep)
         pv = S((P,), jnp.int32)
         for rel, st in self._rels.items():
             ladder = self.committed_ladder(rel, ub, horizon)
@@ -1297,7 +1292,9 @@ class RegionStore:
         callbacks (banned on the serving path); tracing the same (function,
         statics, shapes) the warm jit cache serves is the static equivalent:
         what the trace contains is what every warm epoch executes.  Pure
-        introspection — no ratchet observation, no store mutation."""
+        introspection — no ratchet observation, no store mutation.
+        ``probe_mosaic`` says whether the probe lowers to a compiled Mosaic
+        kernel (a TPU backend) rather than interpret mode."""
         from repro.kernels import count_pallas_calls
         if not self.device_resident:
             return {}
@@ -1308,13 +1305,14 @@ class RegionStore:
         for rel, st in self._rels.items():
             cc = int(st.lc_ins.key.shape[-1])  # current committed rung
             li = _packed_index(np.zeros((0, st.arity), np.int32),
-                               self.shard_w, st.arity, capacity=P)
+                               self.shard_w, st.arity, capacity=P,
+                               mesh=self.mesh)
             fold_calls = count_pallas_calls(
                 lambda ba, ci, cd, ui, ud: _commit_fold_impl(
                     ba, ci, cd, ui, ud, cins_cap=cc, cdel_cap=cc,
                     sharded=sharded, use_kernel=use_k),
                 st.lb, st.lc_ins, st.lc_del, li, li)
-            probe_calls = 0
+            probe_calls, probe_mosaic = 0, False
             for reg in self.projections.values():
                 if reg.rel != rel or reg.derived:
                     continue
@@ -1327,9 +1325,20 @@ class RegionStore:
                 qk = ((jnp.zeros(P, jnp.int64), jnp.zeros(P, jnp.int64))
                       if composite else jnp.zeros(P, jnp.int64))
                 qv = jnp.zeros(P, jnp.int32)
-                probe_calls = count_pallas_calls(
-                    lambda a, b: vi.signed_member(a, b, use_kernel=True),
-                    qk, qv)
+
+                def probe(vi, a, b):  # regions as arguments, not constants
+                    return vi.signed_member(a, b, use_kernel=True)
+                probe_calls = count_pallas_calls(probe, vi, qk, qv)
+                # a compiled kernel lowers to a Mosaic custom call; interpret
+                # mode lowers the kernel body to plain HLO instead.  Lowered
+                # from unplaced shapes: on a mesh the kernel runs per worker
+                # inside shard_map, and jit cannot partition a Mosaic call
+                # over the shard's mesh placement
+                shapes = jax.tree.map(
+                    lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype),
+                    (vi, qk, qv))
+                probe_mosaic = "tpu_custom_call" in jax.jit(probe).lower(
+                    *shapes).as_text()
                 break
             out[rel] = {
                 "composite": st.lb.lo is not None,
@@ -1337,6 +1346,7 @@ class RegionStore:
                 "fold_pallas_calls": int(fold_calls),
                 "fused_fold": bool(use_k and fold_calls == 1),
                 "probe_pallas_calls": int(probe_calls),
+                "probe_mosaic": bool(probe_mosaic),
             }
         return out
 
@@ -1479,7 +1489,8 @@ class RegionStore:
     def _normalize_device(self, rel: str, ph: np.ndarray, pl: np.ndarray,
                           pw: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:
         st = self._rel(rel)
-        dh, dl, dw = jnp.asarray(ph), jnp.asarray(pl), jnp.asarray(pw)
+        dh, dl, dw = (csr.place_for_workers(x, self.mesh)
+                      for x in (ph, pl, pw))
         with _device_scope():
             oih, oil, ni, odh, odl, nd = _normalize_core(
                 dh, dl, dw, st.lb, st.lc_ins, st.lc_del,
@@ -1545,8 +1556,8 @@ class RegionStore:
                                           use_kernel=use_k)
                 zero = np.zeros(self.shard_w, np.int64) if self.shard_w \
                     else 0
-                st.lc_ins = _empty_packed(self.shard_w, st.arity)
-                st.lc_del = _empty_packed(self.shard_w, st.arity)
+                st.lc_ins = _empty_packed(self.shard_w, st.arity, self.mesh)
+                st.lc_del = _empty_packed(self.shard_w, st.arity, self.mesh)
                 st.n_live = [new_nb if self.shard_w else int(new_nb),
                              zero, zero]
                 self.stats.live_compactions += 1
@@ -1658,7 +1669,8 @@ class RegionStore:
                                         np.zeros(r_ins.shape[0], np.int32),
                                         np.int64(csr.SENTINEL),
                                         cap=self._probe_cap(
-                                            rel, r_ins.shape[0]))
+                                            rel, r_ins.shape[0]),
+                                        mesh=self.mesh)
                     need = need or bool(_any_member(
                         st.lc_del, qk, qv, sharded=bool(self.shard_w)))
                 if not need:
@@ -1726,10 +1738,6 @@ class RegionStore:
             self._sync_compile_stats()
             return
         use_k = _merge_kernel_on()
-        # donation would kill the old committed buffers the moment a fold
-        # runs, stranding the rollback target — take the undonated variant
-        # whenever a fault could abort the commit midway
-        fold_fn = _commit_fold_safe if faults.active() else _commit_fold
         # ---- stage: compute every fold output, store untouched ------------
         staged_rels = []  # (st, new_cins, new_cdel, n_live)
         for rel, (r_ins, r_dels) in batches.items():
@@ -1744,18 +1752,20 @@ class RegionStore:
             faults.fire("store.commit.fold")
             li = _packed_index(r_ins, self.shard_w, st.arity,
                                capacity=self._delta_cap(rel,
-                                                        r_ins.shape[0]))
+                                                        r_ins.shape[0]),
+                               mesh=self.mesh)
             self.ratchet.observe(("delta", rel), li.key.shape[-1])
             ld = _packed_index(r_dels, self.shard_w, st.arity,
                                capacity=self._delta_cap(rel,
-                                                        r_dels.shape[0]))
+                                                        r_dels.shape[0]),
+                               mesh=self.mesh)
             self.ratchet.observe(("delta", rel), ld.key.shape[-1])
             nb, nci, ncd = st.n_live
             need = max(_maxn(np.asarray(nci) + np.asarray(_count_of(li))),
                        _maxn(np.asarray(ncd) + np.asarray(_count_of(ld))))
             cc = self._committed_cap(rel, need)
             with _device_scope():
-                new_ci, new_cd = fold_fn(
+                new_ci, new_cd = _commit_fold(
                     st.lb, st.lc_ins, st.lc_del, li, ld,
                     cins_cap=cc, cdel_cap=cc,
                     sharded=bool(self.shard_w), use_kernel=use_k)
@@ -1781,7 +1791,7 @@ class RegionStore:
                       + np.asarray(_count_of(reg.d_udel))))
             cc = self._committed_cap(reg.rel, need)
             with _device_scope():
-                d_cins, d_cdel = fold_fn(
+                d_cins, d_cdel = _commit_fold(
                     reg.d_base, reg.d_cins, reg.d_cdel, reg.d_uins,
                     reg.d_udel, cins_cap=cc, cdel_cap=cc,
                     sharded=bool(self.shard_w), use_kernel=use_k)
@@ -1981,12 +1991,15 @@ class RegionStore:
         if len(by_name) != len(meta["names"]):
             raise ValueError("snapshot leaves do not match meta['names']")
 
+        def put(x):  # default placement for an unsharded store
+            return csr.place_worker_stack(x, self.mesh)
+
         def pull(prefix) -> IndexData:
             lo = by_name.get(f"{prefix}.lo")
-            return IndexData(jnp.asarray(by_name[f"{prefix}.key"]),
-                             jnp.asarray(by_name[f"{prefix}.val"]),
-                             jnp.asarray(by_name[f"{prefix}.n"]),
-                             None if lo is None else jnp.asarray(lo))
+            return IndexData(put(by_name[f"{prefix}.key"]),
+                             put(by_name[f"{prefix}.val"]),
+                             put(by_name[f"{prefix}.n"]),
+                             None if lo is None else put(lo))
 
         def nval(v):
             arr = np.asarray(v, np.int64)
@@ -2012,7 +2025,8 @@ class RegionStore:
         for i, spec in enumerate(meta["projections"]):
             reg = _Regions(tuple(spec["key_pos"]), int(spec["ext_pos"]),
                            rel=spec["rel"], rel_arity=int(spec["rel_arity"]),
-                           shard_w=self.shard_w, device_resident=True,
+                           shard_w=self.shard_w, mesh=self.mesh,
+                           device_resident=True,
                            narrow=bool(spec["narrow"]),
                            derived=bool(spec["derived"]), _store=self)
             if not reg.derived:

@@ -39,13 +39,12 @@ from repro import compat, faults
 from repro.core import compilestats, csr
 from repro.core import delta as _delta
 from repro.core.bigjoin import BigJoinConfig
+from repro.core.csr import AXIS  # noqa: F401  (re-exported)
 from repro.core.dataflow_index import VersionedIndex
 from repro.core.plan import Plan
 from repro.errors import (CapacityOverflow, ESCALATES_BATCH, ESCALATES_OUT,
                           ESCALATES_ROUTE, OVF_OUT, OVF_QUEUE, OVF_ROUTE,
                           OVF_SEED, _KIND_BITS)
-
-AXIS = "workers"
 
 
 # ---------------------------------------------------------------------------
@@ -563,7 +562,9 @@ def build_per_worker(plan: Plan, dcfg: DistConfig):
         bits = jax.lax.psum((state.overflow >> shifts) & 1, dcfg.axis)
         ovf = jnp.where(bits > 0, jnp.int32(1) << shifts, 0
                         ).sum().astype(jnp.int32)
-        max_load = jax.lax.pmax(state.recv_load, dcfg.axis)
+        # TPU all-reduces lower only Sum for 64-bit integers: gather the
+        # per-worker loads and reduce locally instead of an s64 pmax
+        max_load = jax.lax.all_gather(state.recv_load, dcfg.axis).max()
         sum_load = jax.lax.psum(state.recv_load, dcfg.axis)
         outs = (count, props, isect, steps, ovf, max_load, sum_load)
         if collect:
@@ -857,6 +858,8 @@ class DistDeltaBigJoin(_delta.DeltaBigJoin):
             raise ValueError(
                 f"shared store is sharded over {store.shard_w} workers, "
                 f"mesh has {self.w}")
+        if store is not None and store.mesh not in (None, mesh):
+            raise ValueError("shared store is placed on another mesh")
         self.dcfg = dcfg
         self._programs: Dict[int, object] = {}
         super().__init__(query, initial_edges, cfg=dcfg.base,
@@ -864,7 +867,7 @@ class DistDeltaBigJoin(_delta.DeltaBigJoin):
                          device_resident=device_resident)
 
     def _new_store(self, edges, compact_ratio):
-        return _delta.RegionStore(edges, shard_w=self.w,
+        return _delta.RegionStore(edges, shard_w=self.w, mesh=self.mesh,
                                   compact_ratio=compact_ratio,
                                   device_resident=self.device_resident)
 

@@ -15,6 +15,8 @@ from typing import Iterator, Tuple
 
 import numpy as np
 
+from repro.core.csr import unique_rows
+
 
 def rmat_graph(scale: int, edge_factor: int = 16, seed: int = 0,
                a: float = 0.57, b: float = 0.19, c: float = 0.19
@@ -37,8 +39,7 @@ def rmat_graph(scale: int, edge_factor: int = 16, seed: int = 0,
         src |= go_down.astype(np.int64) << bit
         dst |= go_right.astype(np.int64) << bit
     keep = src != dst
-    edges = np.unique(np.stack([src[keep], dst[keep]], 1), axis=0)
-    return edges.astype(np.int32)
+    return unique_rows(np.stack([src[keep], dst[keep]], 1).astype(np.int32))
 
 
 def uniform_graph(num_vertices: int, num_edges: int, seed: int = 0
@@ -47,8 +48,7 @@ def uniform_graph(num_vertices: int, num_edges: int, seed: int = 0
     u = rng.integers(0, num_vertices, num_edges)
     v = rng.integers(0, num_vertices, num_edges)
     keep = u != v
-    return np.unique(np.stack([u[keep], v[keep]], 1).astype(np.int32),
-                     axis=0)
+    return unique_rows(np.stack([u[keep], v[keep]], 1).astype(np.int32))
 
 
 @dataclasses.dataclass

@@ -112,10 +112,7 @@ def clear() -> None:
 
 
 def active() -> bool:
-    """True when ANY fault point is armed.  Transactional code paths use
-    this to prefer rollback-safe variants (e.g. the commit fold runs
-    without buffer donation while faults are armed, so a mid-commit
-    rollback never resurrects a donated buffer)."""
+    """True when ANY fault point is armed."""
     with _lock:
         _load_env_locked()
         return bool(_sched)

@@ -11,8 +11,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels.flash_attention.flash_attention import _flash_call
+from repro.kernels.intersect.ops import default_interpret
 
-_INTERPRET = jax.default_backend() != "tpu"
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -37,5 +37,5 @@ def mha(q: jax.Array, k: jax.Array, v: jax.Array, causal: bool = True,
     vh = v.transpose(0, 2, 1, 3).reshape(B * Hq, -1, Dh)
     o = _flash_call(qh, kh, vh, causal=causal, window=window,
                     softcap=softcap, scale=scale, q_offset=q_offset,
-                    interpret=_INTERPRET)
+                    interpret=default_interpret())
     return o.reshape(B, Hq, Sq, Dh).transpose(0, 2, 1, 3)

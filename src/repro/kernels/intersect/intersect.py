@@ -1,42 +1,34 @@
-"""Pallas TPU kernels: batched sorted-membership (the Intersect hot spot).
+"""Pallas TPU kernel: batched sorted-membership (the Intersect hot spot).
 
 The innermost operation of the WCOJ dataflow is "does extension e of prefix p
 exist in relation R_i?" — a lookup of (key, val) in a lexicographically
 sorted pair of arrays.  The paper uses CPU hash tables; the TPU-native
 structure is a two-level sorted search (DESIGN.md §2):
 
-  level 1 (VMEM): a *router* holding every SEG-th (key,val) pair.  A
-      fixed-depth vectorized binary search over the router (VMEM gathers —
-      cheap on TPU) locates the SEG-aligned segment of each query.
-  level 2 (VMEM): the index is stored segment-major as a [num_segments, SEG]
-      tile, so the router is simply column 0 and each query's segment is one
-      *row gather*.  All BQ segments are fetched as a single [BQ, SEG] tile
-      and reduced with a lane-wise compare — there is no per-query probe
-      loop; the whole query block resolves in O(log S) vector ops plus one
-      gather, instead of BQ serialized dynamic-slices.
+  level 1 (router): every SEG-th entry of the index.  A fixed-depth
+      vectorized binary search over the router locates each query's
+      SEG-aligned segment.  It runs in XLA ahead of the kernel
+      (``ops._region_operands``) and hands the kernel one segment id per
+      query and region.
+  level 2 (this kernel): the index stays in HBM, viewed segment-major as
+      [num_segments, SEG] tiles.  The kernel DMAs each query's segment row
+      into a [BQ, SEG] VMEM tile and reduces it with one lane-wise compare
+      per word — the whole query block resolves with BQ row copies and
+      O(words) vector ops, whatever the index size.
 
-Design notes (fused extension pipeline, DESIGN.md §"Fused extension
-pipeline"):
+Mosaic has no 64-bit vectors and no in-kernel vector gather, so the kernel
+sees only int32: every key column arrives as order-preserving int32 words
+(``ops.split_words``: an int64 becomes its signed high word and its low word
+with the sign bit flipped), and the only gathers are the row DMAs, addressed
+by segment ids read from SMEM.  All index arithmetic is explicitly int32 —
+the package runs with x64 on, where a bare Python int would trace as int64.
 
-  * SEG = 128 aligns the segment row with the VPU lane width, so the level-2
-    compare is exactly one vector op per query row.
-  * The query block (BQ per grid step) bounds VMEM: the working set per grid
-    step is the full segment-major index (cap·12 B), one [BQ, SEG] gathered
-    key tile (BQ·SEG·8 B) + val tile (BQ·SEG·4 B), and the BQ·12 B query
-    columns.  With BQ = 256 the gathered tiles are 384 KiB; the index tile
-    dominates and caps the per-shard index at ~1 M entries per 12 MiB of
-    VMEM.  Larger shards need a second router level (not required below
-    2^23 entries per worker) or an HBM-resident index with per-segment DMA.
-  * the multi-region kernel (``_make_multi_member_kernel``) evaluates *all*
-    positive and negative regions of
-    a :class:`~repro.core.dataflow_index.VersionedIndex` in one
-    ``pallas_call`` and returns the signed hit counts, replacing R separate
-    kernel launches (and R round-trips through HBM for the query batch) with
-    one fused pass — the multi-region fusion of this PR's extension-step
-    pipeline.
+The multi-region kernel evaluates *all* positive and negative regions of a
+:class:`~repro.core.dataflow_index.VersionedIndex` in one ``pallas_call``
+and returns the signed hit counts (wpos, wneg) — one launch per membership
+probe regardless of how many LSM regions back the index.
 
-The kernels return int32 hit bits/counts per query.  ref.py is the pure-jnp
-oracle (identical fixed-depth lexicographic search, no tiling); parity is
+ref.py and ``csr.index_member`` are the pure-jnp oracle; parity is
 bit-exact.
 """
 from __future__ import annotations
@@ -47,160 +39,72 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 # segment length (one VPU lane row per segment fetch) — canonical constant
 # lives with the index structure so capacity rounding cannot drift from it
 from repro.core.csr import SEG  # noqa: F401  (re-exported for ops.py)
 
 BQ = 256  # queries per grid step
+SMEM_TILE = 1024  # 1-D SMEM blocks are whole multiples of this
+
+_I0 = np.int32(0)
 
 
-def _router_depth(num_segments: int) -> int:
-    return max(int(np.ceil(np.log2(max(num_segments, 2)))), 1) + 1
+def seg_block_len(num_regions: int) -> int:
+    """Length of one grid step's SMEM block of segment ids: R*BQ, rounded
+    up to the SMEM tiling."""
+    return -(-num_regions * BQ // SMEM_TILE) * SMEM_TILE
 
 
-def _two_level_hits(keys2d: jax.Array, vals2d: jax.Array, n: jax.Array,
-                    qk: jax.Array, qv: jax.Array,
-                    los2d: jax.Array | None = None,
-                    ql: jax.Array | None = None) -> jax.Array:
-    """Vectorized two-level membership of (qk[, ql], qv) in a segment-major
-    index.
+def _make_probe_kernel(num_pos: int, num_neg: int, num_words: int):
+    """Kernel over ``num_pos`` positive + ``num_neg`` negative regions whose
+    entries are ``num_words`` int32 words each (key words, then val).
 
-    keys2d/vals2d: [num_segments, SEG] sorted lexicographically row-major
-    with sentinel padding; n: [] live entries; qk/qv: [BQ].  Returns int32
-    [BQ] hit bits.  Column 0 of keys2d/vals2d *is* the router.  For a
-    composite 2-word key, ``los2d`` [num_segments, SEG] int64 carries the
-    secondary word (sentinel padding sorts above all live entries, like the
-    hi word) and ``ql`` [BQ] the query lo word — the router compare and the
-    lane compare become 3-word lexicographic, same tile shapes, one extra
-    [BQ, SEG] row gather.
+    Refs: seg (SMEM [seg_block_len(R)]: segment id of each query in each
+    region, region-major), nlive (VMEM [R, BQ, 1]: live entries in that
+    segment), the query words (VMEM [W, BQ, 1]), then R*W index word arrays
+    (HBM [S_r, SEG]);
+    outputs wpos/wneg (VMEM [BQ, 1] int32 hit counts); scratch: one
+    [W, BQ, SEG] row tile and W DMA semaphores.
     """
-    num_segments = keys2d.shape[0]
-    composite = los2d is not None
-    rk = keys2d[:, 0]
-    rl = los2d[:, 0] if composite else None
-    rv = vals2d[:, 0]
+    R, W = num_pos + num_neg, num_words
 
-    # ---- level 1: vectorized binary search over the implicit router -------
-    lo = jnp.zeros(qk.shape, jnp.int32)
-    hi = jnp.full(qk.shape, num_segments, jnp.int32)
-
-    def body(_, lohi):
-        lo, hi = lohi
-        mid = (lo + hi) >> 1
-        mc = jnp.clip(mid, 0, num_segments - 1)
-        mk = rk[mc]
-        mv = rv[mc]
-        # segment leader less-or-equal than query -> go right
-        if composite:
-            ml = rl[mc]
-            le = (mk < qk) | ((mk == qk)
-                             & ((ml < ql) | ((ml == ql) & (mv <= qv))))
-        else:
-            le = (mk < qk) | ((mk == qk) & (mv <= qv))
-        sel = lo < hi
-        lo = jnp.where(le & sel, mid + 1, lo)
-        hi = jnp.where(~le & sel, mid, hi)
-        return lo, hi
-
-    lo, _ = jax.lax.fori_loop(0, _router_depth(num_segments), body, (lo, hi))
-    seg = jnp.maximum(lo - 1, 0)  # last segment whose leader <= query
-
-    # ---- level 2: one [BQ, SEG] row gather + lane-wise compare ------------
-    kseg = keys2d[seg]  # [BQ, SEG]
-    vseg = vals2d[seg]
-    col = jax.lax.broadcasted_iota(jnp.int32, kseg.shape, 1)
-    idx = seg[:, None] * SEG + col
-    hit = (kseg == qk[:, None]) & (vseg == qv[:, None]) & (idx < n)
-    if composite:
-        hit = hit & (los2d[seg] == ql[:, None])
-    return hit.max(axis=1).astype(jnp.int32)
-
-
-def member_kernel(keys_ref, vals_ref, n_ref, qk_ref, qv_ref, out_ref):
-    """One grid step: BQ queries against the full segment-major (keys, vals).
-
-    No per-query probe loop: the segment of every query is located by the
-    shared router search and gathered in one [BQ, SEG] tile.
-    """
-    out_ref[...] = _two_level_hits(keys_ref[...], vals_ref[...], n_ref[0],
-                                   qk_ref[...], qv_ref[...])
-
-
-def member_kernel_lex(keys_ref, los_ref, vals_ref, n_ref, qk_ref, ql_ref,
-                      qv_ref, out_ref):
-    """Composite-key variant: BQ (qk, ql, qv) queries, 3-word lex compare."""
-    out_ref[...] = _two_level_hits(keys_ref[...], vals_ref[...], n_ref[0],
-                                   qk_ref[...], qv_ref[...],
-                                   los2d=los_ref[...], ql=ql_ref[...])
-
-
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def _member_call(keys2d, vals2d, n, qk, qv, interpret: bool = True,
-                 los2d=None, ql=None):
-    B = qk.shape[0]
-    num_segments = keys2d.shape[0]
-    grid = (B // BQ,)
-    composite = los2d is not None
-    full = pl.BlockSpec((num_segments, SEG), lambda i: (0, 0))
-    in_specs = [full] + ([full] if composite else []) + [
-        full,
-        pl.BlockSpec((1,), lambda i: (0,)),
-        pl.BlockSpec((BQ,), lambda i: (i,)),  # query tile
-    ] + ([pl.BlockSpec((BQ,), lambda i: (i,))] if composite else []) + [
-        pl.BlockSpec((BQ,), lambda i: (i,)),
-    ]
-    operands = ((keys2d, los2d, vals2d, n, qk, ql, qv) if composite
-                else (keys2d, vals2d, n, qk, qv))
-    return pl.pallas_call(
-        member_kernel_lex if composite else member_kernel,
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((BQ,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((B,), jnp.int32),
-        interpret=interpret,
-    )(*operands)
-
-
-# ---------------------------------------------------------------------------
-# multi-region membership: every region of a VersionedIndex in one launch
-# ---------------------------------------------------------------------------
-
-def _make_multi_member_kernel(num_pos: int, num_neg: int,
-                              composite: bool = False):
-    """Kernel over ``num_pos`` positive + ``num_neg`` negative regions.
-
-    Ref layout: [keys2d, vals2d, n] per region (positives first) — or
-    [keys2d, los2d, vals2d, n] when ``composite`` — then qk[, ql], qv;
-    outputs (wpos, wneg) — int32 hit counts over the positive / negative
-    regions, from which membership is ``wpos - wneg > 0`` and deletion is
-    ``wneg > 0``.
-    """
-    R = num_pos + num_neg
-    per = 4 if composite else 3
-    nq = 3 if composite else 2
-
-    def kernel(*refs):
-        region_refs = refs[:per * R]
-        qrefs = refs[per * R: per * R + nq]
-        wpos_ref, wneg_ref = refs[per * R + nq], refs[per * R + nq + 1]
-        if composite:
-            qk, ql, qv = (q[...] for q in qrefs)
-        else:
-            (qk, qv), ql = (q[...] for q in qrefs), None
-        wpos = jnp.zeros(qk.shape, jnp.int32)
-        wneg = jnp.zeros(qk.shape, jnp.int32)
+    def kernel(seg_ref, nlive_ref, q_ref, *refs):
+        idx_refs = refs[:R * W]
+        wpos_ref, wneg_ref, rows, sems = refs[R * W:]
+        col = jax.lax.broadcasted_iota(jnp.int32, (BQ, SEG), 1)
+        wpos = jnp.zeros((BQ, 1), jnp.int32)
+        wneg = jnp.zeros((BQ, 1), jnp.int32)
         for r in range(R):
-            regs = region_refs[per * r: per * (r + 1)]
-            if composite:
-                keys_ref, los_ref, vals_ref, n_ref = regs
-                hits = _two_level_hits(keys_ref[...], vals_ref[...], n_ref[0],
-                                       qk.astype(keys_ref.dtype), qv,
-                                       los2d=los_ref[...], ql=ql)
-            else:
-                keys_ref, vals_ref, n_ref = regs
-                hits = _two_level_hits(keys_ref[...], vals_ref[...], n_ref[0],
-                                       qk.astype(keys_ref.dtype), qv)
+            words = idx_refs[r * W:(r + 1) * W]
+
+            def copies(i, r=r, words=words):
+                s = seg_ref[np.int32(r * BQ) + i]
+                return [pltpu.make_async_copy(
+                    words[w].at[pl.ds(s, 1)],
+                    rows.at[np.int32(w), pl.ds(i, 1)],
+                    sems.at[np.int32(w)]) for w in range(W)]
+
+            def start(i, c, copies=copies):
+                for cp in copies(i):
+                    cp.start()
+                return c
+
+            def wait(i, c, copies=copies):
+                for cp in copies(i):
+                    cp.wait()
+                return c
+
+            # jnp.int32 bounds: with x64 on, Python/NumPy scalar bounds give
+            # the loop an index type Mosaic cannot lower
+            zero, bq = jnp.int32(0), jnp.int32(BQ)
+            jax.lax.fori_loop(zero, bq, start, zero)
+            jax.lax.fori_loop(zero, bq, wait, zero)
+            hit = col < nlive_ref[np.int32(r)]
+            for w in range(W):
+                hit = hit & (rows[np.int32(w)] == q_ref[np.int32(w)])
+            hits = jnp.max(hit.astype(jnp.int32), axis=1, keepdims=True)
             if r < num_pos:
                 wpos = wpos + hits
             else:
@@ -212,34 +116,32 @@ def _make_multi_member_kernel(num_pos: int, num_neg: int,
 
 
 @functools.partial(jax.jit, static_argnames=("num_pos", "interpret"))
-def _multi_member_call(regions, qk, qv, num_pos: int,
-                       interpret: bool = True, ql=None):
-    """regions: flat tuple of (keys2d [S_r, SEG], vals2d, n [1]) triples —
-    or (keys2d, los2d, vals2d, n) quads with ``ql`` for composite keys —
-    positives first.  Returns (wpos, wneg) int32 [B]."""
-    B = qk.shape[0]
-    grid = (B // BQ,)
-    composite = ql is not None
-    in_specs = []
-    operands = []
-    for reg in regions:
-        keys2d = reg[0]
-        s = keys2d.shape[0]
-        full = pl.BlockSpec((s, SEG), lambda i: (0, 0))
-        in_specs += [full] * (len(reg) - 1) + [
-            pl.BlockSpec((1,), lambda i: (0,))]
-        operands += list(reg)
-    qspec = pl.BlockSpec((BQ,), lambda i: (i,))
-    in_specs += [qspec] * (3 if composite else 2)
-    operands += [qk, ql, qv] if composite else [qk, qv]
+def probe_call(seg, nlive, qwords, regions, num_pos: int,
+               interpret: bool = True):
+    """The multi-region probe launch on prepared operands.
+
+    seg: [Bp//BQ * seg_block_len(R)] int32 segment ids, one zero-padded
+    block per query block, region-major inside it; nlive: [R, Bp, 1] int32; qwords: [W, Bp, 1] int32; regions: R
+    tuples of W int32 [S_r, SEG] word arrays (positives first).  Bp is a BQ
+    multiple.  Returns (wpos, wneg) int32 [Bp, 1]."""
+    R = len(regions)
+    W, Bp = qwords.shape[0], qwords.shape[1]
+    col = pl.BlockSpec((BQ, 1), lambda b: (b, _I0))
+    in_specs = [
+        pl.BlockSpec((seg_block_len(R),), lambda b: (b,),
+                     memory_space=pltpu.SMEM),
+        pl.BlockSpec((R, BQ, 1), lambda b: (_I0, b, _I0)),
+        pl.BlockSpec((W, BQ, 1), lambda b: (_I0, b, _I0)),
+    ] + [pl.BlockSpec(memory_space=pl.ANY)] * (R * W)
+    operands = [seg, nlive, qwords] + [w for reg in regions for w in reg]
     return pl.pallas_call(
-        _make_multi_member_kernel(num_pos, len(regions) - num_pos,
-                                  composite=composite),
-        grid=grid,
+        _make_probe_kernel(num_pos, R - num_pos, W),
+        grid=(Bp // BQ,),
         in_specs=in_specs,
-        out_specs=(pl.BlockSpec((BQ,), lambda i: (i,)),
-                   pl.BlockSpec((BQ,), lambda i: (i,))),
-        out_shape=(jax.ShapeDtypeStruct((B,), jnp.int32),
-                   jax.ShapeDtypeStruct((B,), jnp.int32)),
+        out_specs=(col, col),
+        out_shape=(jax.ShapeDtypeStruct((Bp, 1), jnp.int32),
+                   jax.ShapeDtypeStruct((Bp, 1), jnp.int32)),
+        scratch_shapes=[pltpu.VMEM((W, BQ, SEG), jnp.int32),
+                        pltpu.SemaphoreType.DMA((W,))],
         interpret=interpret,
     )(*operands)

@@ -36,9 +36,12 @@ import numpy as np
 from jax.experimental import pallas as pl
 
 from repro.core.csr import SEG  # canonical segment length (see csr.py)
-from repro.kernels.intersect.intersect import _router_depth
 
 BQ = 256  # queries per grid step
+
+
+def _router_depth(num_segments: int) -> int:
+    return max(int(np.ceil(np.log2(max(num_segments, 2)))), 1) + 1
 
 
 def _rank_counts(keys2d: jax.Array, vals2d: jax.Array, n: jax.Array,

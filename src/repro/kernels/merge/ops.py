@@ -1,20 +1,17 @@
-"""Routing for the merge rank kernel: compiled Mosaic on TPU, interpreted
-kernel elsewhere.
+"""Routing for the merge rank kernel.
 
-Platform gating matches the intersect kernels (``default_interpret``): on a
-TPU backend the compiled kernel runs IF the VMEM-resident index fits the
-budget (an over-budget index falls back to the jnp oracle instead of
-failing Mosaic compilation); off-TPU the interpreted kernel is the
-*production* path — interpret mode lowers the kernel body through XLA, so
-the 4-device CPU CI lane exercises the same fused commit-fold code path the
-TPU runs, with bit-exact results (tests/test_merge_kernel.py).
+The rank kernel is off the default path (``repro.kernels.OFF_DEFAULT_PATH``:
+it does not compile for TPU), so production rank queries run the jnp
+searches (``csr.index_ranks``).  Callers that ask for the kernel get it in
+the mode ``default_interpret`` picks — interpret mode off-TPU, where it is
+bit-exact against the jnp oracle (tests/test_merge_kernel.py).
 """
 from __future__ import annotations
 
 import jax
 
+from repro.kernels.intersect.ops import default_interpret
 from repro.kernels.merge.merge import rank_counts
-from repro.kernels.merge.ref import rank_ref
 
 
 def rank_lt_le(keys: jax.Array, vals: jax.Array, n: jax.Array,
@@ -23,19 +20,9 @@ def rank_lt_le(keys: jax.Array, vals: jax.Array, n: jax.Array,
     """(lt, le) merge ranks of each (qk[, qlo], qv) in the sorted index.
 
     ``lo``/``qlo``: the int64 secondary words when the index carries
-    composite 2-word keys.  ``interpret=None`` defers to platform
-    detection: compiled kernel on TPU when the index fits the VMEM budget,
-    jnp oracle when it does not, interpreted kernel off-TPU.  An explicit
-    bool forces that kernel mode.
+    composite 2-word keys.  ``interpret=None`` defers to
+    :func:`default_interpret`; an explicit bool forces that kernel mode.
     """
-    if interpret is None:
-        from repro.kernels.intersect.ops import (FUSED_VMEM_BUDGET,
-                                                 default_interpret)
-        interpret = default_interpret(None)
-        if not interpret:
-            idx_bytes = keys.shape[-1] * (keys.dtype.itemsize + 4
-                                          + (8 if lo is not None else 0))
-            if idx_bytes > FUSED_VMEM_BUDGET:
-                return rank_ref(keys, vals, n, qk, qv, lo=lo, qlo=qlo)
-    return rank_counts(keys, vals, n, qk, qv, interpret=bool(interpret),
-                       lo=lo, qlo=qlo)
+    return rank_counts(keys, vals, n, qk, qv,
+                       interpret=default_interpret(interpret), lo=lo,
+                       qlo=qlo)

@@ -7,8 +7,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels.segment_ops.segment_ops import BE, _segment_sum_call
+from repro.kernels.intersect.ops import default_interpret
 
-_INTERPRET = jax.default_backend() != "tpu"
 
 
 @functools.partial(jax.jit, static_argnames=("num_segments", "is_sorted"))
@@ -30,6 +30,7 @@ def segment_sum(data: jax.Array, seg_ids: jax.Array, num_segments: int,
         seg_ids = jnp.concatenate(
             [seg_ids, jnp.full((Ep - E,), num_segments, seg_ids.dtype)])
     partials, segmap = _segment_sum_call(
-        data, seg_ids.astype(jnp.int32), num_segments, interpret=_INTERPRET)
+        data, seg_ids.astype(jnp.int32), num_segments,
+        interpret=default_interpret())
     out = jnp.zeros((num_segments, D), jnp.float32)
     return out.at[segmap].add(partials, mode="drop")

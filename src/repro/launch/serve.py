@@ -360,7 +360,7 @@ def main(argv=None):
     ap.add_argument("--prewarm", action="store_true",
                     help="walk the AOT capacity ladder before the first "
                     "epoch so warm epochs trigger zero XLA compiles "
-                    "(stream mode; pairs with REPRO_COMPILE_CACHE)")
+                    "(stream mode; pairs with the persistent compile cache)")
     ap.add_argument("--verify", action="store_true",
                     help="check the maintained total against full "
                     "recomputation at the end (stream mode)")

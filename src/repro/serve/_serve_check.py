@@ -62,8 +62,8 @@ def worker(args) -> int:
 
     def note(msg):
         # stage timings on stderr: CI logs show where a slow run spends
-        # its wall clock (cold XLA ladder walks dominate without
-        # REPRO_COMPILE_CACHE)
+        # its wall clock (cold XLA ladder walks dominate without a warm
+        # persistent compile cache)
         sys.stderr.write(f"[serve_check +{time.time() - t_start:7.1f}s] "
                          f"{msg}\n")
         sys.stderr.flush()
@@ -394,8 +394,10 @@ def supervise(args) -> int:
             # write the shared persistent compile cache — a kill during a
             # cache write leaves a torn entry that poisons every later
             # process reading it (observed as compaction-count assertion
-            # failures and segfaults on deserialized executables)
-            env.pop("REPRO_COMPILE_CACHE", None)
+            # failures and segfaults on deserialized executables), so it
+            # gets a private cache that dies with the run's temp dir
+            env["JAX_COMPILATION_CACHE_DIR"] = os.path.join(tmp,
+                                                            "victim-cache")
         sys.stderr.write(f"[supervise] child {extra or ['oracle']}...\n")
         sys.stderr.flush()
         t0 = time.time()

@@ -30,7 +30,7 @@ Scheduling (DESIGN.md §9):
 - **Pipelined epochs.**  A prep thread runs the pure-host stage A
   (``session.prepare``: validate/pack/pad, no jax call) while the apply
   thread runs stage B (``update(prepared=...)``: jitted normalize →
-  dataflows → donated commit fold) — batch k+1's host work overlaps batch
+  dataflows → commit fold) — batch k+1's host work overlaps batch
   k's device work.  Round-robin across tenants in both stages keeps
   admission fair.  The SINGLE apply thread is also a correctness
   property, not just a scheduling choice: two host threads dispatching

@@ -10,7 +10,7 @@ Three layers of the latency-tail contract:
   batch-size stream that straddles every pow2 bucket and repeatedly
   crosses committed-region rungs triggers ZERO XLA compiles, local and
   mesh alike (``EpochResult.compile_events == 0`` every epoch);
-- the persistent cross-process cache (``REPRO_COMPILE_CACHE``) — a second
+- the persistent cross-process cache (``JAX_COMPILATION_CACHE_DIR``) — a second
   process walking the same ladder compiles nothing: every lowering is a
   cache hit and the cache gains no new entries.
 """
@@ -182,7 +182,7 @@ print(json.dumps({"compiles": compilestats.total(),
 @pytest.mark.slow
 def test_persistent_cache_second_process_compiles_nothing(tmp_path):
     env = dict(os.environ)
-    env["REPRO_COMPILE_CACHE"] = str(tmp_path / "xla-cache")
+    env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path / "xla-cache")
     env["JAX_PLATFORMS"] = "cpu"
     src = os.path.join(os.path.dirname(__file__), os.pardir, "src")
     env["PYTHONPATH"] = os.path.abspath(src) + os.pathsep + \
@@ -202,12 +202,13 @@ def test_persistent_cache_second_process_compiles_nothing(tmp_path):
 
 
 def test_enable_persistent_cache_is_stable(monkeypatch):
-    """Without a path (arg or env) enabling is a no-op, and re-enabling the
-    active dir is idempotent — flipping jax's global cache config
-    mid-process is reserved for process start (module import)."""
-    monkeypatch.delenv(compilestats.ENV_VAR, raising=False)
+    """Importing the package enabled the cache where the environment says,
+    and re-enabling is idempotent — flipping jax's global cache config
+    mid-process is reserved for process start (package import)."""
+    import jax
     before = compilestats.cache_dir()
-    assert compilestats.enable_persistent_cache() is None
+    assert before == compilestats.cache_dir_for(os.environ)
+    assert jax.config.jax_compilation_cache_dir == before
+    monkeypatch.setenv(compilestats.ENV_VAR, "/nonexistent/elsewhere")
+    assert compilestats.enable_persistent_cache() == before
     assert compilestats.cache_dir() == before  # unchanged
-    if before is not None:  # idempotent re-enable of the active dir
-        assert compilestats.enable_persistent_cache(before) == before

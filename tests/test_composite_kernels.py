@@ -125,25 +125,8 @@ BAD_PRIMS = {"pure_callback", "io_callback", "debug_callback", "callback",
 
 
 def _prims_of(closed):
-    def _subjaxprs(v):
-        if isinstance(v, jax.core.ClosedJaxpr):
-            yield v.jaxpr
-        elif isinstance(v, jax.core.Jaxpr):
-            yield v
-        elif isinstance(v, (tuple, list)):
-            for x in v:
-                yield from _subjaxprs(x)
-
-    def walk(jaxpr, seen):
-        for eqn in jaxpr.eqns:
-            seen.add(eqn.primitive.name)
-            for v in eqn.params.values():
-                for sub in _subjaxprs(v):
-                    walk(sub, seen)
-
-    seen = set()
-    walk(closed.jaxpr, seen)
-    return seen
+    from repro.compat import primitive_names
+    return primitive_names(closed)
 
 
 @pytest.mark.parametrize("shard_w", [0, 4], ids=["local", "w4"])

@@ -235,26 +235,6 @@ _HOST_PRIMS = {"pure_callback", "io_callback", "debug_callback", "callback",
                "infeed", "outfeed", "host_local_array_to_global_array"}
 
 
-def _walk(jaxpr, visit):
-    import jax
-    for eqn in jaxpr.eqns:
-        visit(eqn)
-        for v in eqn.params.values():
-            for sub in _subjaxprs(v):
-                _walk(sub, visit)
-
-
-def _subjaxprs(v):
-    import jax
-    if isinstance(v, jax.core.ClosedJaxpr):
-        yield v.jaxpr
-    elif isinstance(v, jax.core.Jaxpr):
-        yield v
-    elif isinstance(v, (tuple, list)):
-        for x in v:
-            yield from _subjaxprs(x)
-
-
 def test_dist_delta_step_has_no_host_roundtrips():
     """Trace the whole per-worker delta program (seed -> while(level step)
     -> psum) and assert: (1) no host-callback primitive anywhere, (2) the
@@ -265,6 +245,7 @@ def test_dist_delta_step_has_no_host_roundtrips():
     import jax.numpy as jnp
     from jax.sharding import Mesh, PartitionSpec as P
     from repro import compat
+    from repro.compat import iter_eqns, primitive_names, subjaxprs
     from repro.configs.wcoj import _abstract_indices
     from repro.core.bigjoin import BigJoinConfig
     from repro.core.distributed import (AXIS, DistConfig, build_per_worker)
@@ -289,24 +270,17 @@ def test_dist_delta_step_has_no_host_roundtrips():
                           out_specs=(P(),) * 7, check_vma=False)
     closed = jax.make_jaxpr(fn)(indices, seed, seed_n, seed_w)
 
-    prims = set()
-    _walk(closed.jaxpr, lambda eqn: prims.add(eqn.primitive.name))
+    prims = primitive_names(closed)
     assert not (prims & _HOST_PRIMS), prims & _HOST_PRIMS
     assert "while" in prims  # the drain loop is in-program
 
     # find every while body; at least one must contain the all_to_all
     # request/response fabric and NONE may contain host primitives
-    bodies = []
-
-    def collect(eqn):
-        if eqn.primitive.name == "while":
-            for v in eqn.params.values():
-                bodies.extend(_subjaxprs(v))
-    _walk(closed.jaxpr, collect)
+    bodies = [sub for eqn in iter_eqns(closed)
+              if eqn.primitive.name == "while"
+              for v in eqn.params.values() for sub in subjaxprs(v)]
     assert bodies
-    loop_prims = set()
-    for b in bodies:
-        _walk(b, lambda eqn: loop_prims.add(eqn.primitive.name))
+    loop_prims = set().union(*map(primitive_names, bodies))
     assert "all_to_all" in loop_prims
     assert not (loop_prims & _HOST_PRIMS)
 

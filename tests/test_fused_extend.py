@@ -23,6 +23,15 @@ from tests.test_generic_join import random_graph
 from repro.kernels import count_pallas_calls  # noqa: E402
 
 
+@pytest.fixture
+def extend_kernel_on(monkeypatch):
+    """Put the fused extend kernel on the path for one test: it is off the
+    default path (it does not compile for TPU) but stays the interpret-mode
+    kernel these tests hold to the jnp stages."""
+    import repro.kernels as K
+    monkeypatch.delitem(K.OFF_DEFAULT_PATH, "extend")
+
+
 def random_versioned(rng, n_base=400, n_delta=60, nv=80):
     """A VersionedIndex with a randomized insert/delete region mix
     (pos = base/cins/uins, neg = cdel/udel) over single-column keys."""
@@ -93,7 +102,7 @@ MOTIFS = [Q.triangle(), Q.four_clique(), Q.diamond()]
 
 
 @pytest.mark.parametrize("q", MOTIFS, ids=lambda q: q.name)
-def test_fused_extend_matches_oracle(q):
+def test_fused_extend_matches_oracle(q, extend_kernel_on):
     g = random_graph(45, 420, 11)
     plan = make_plan(q)
     rels = {Q.EDGE: g.edges}
@@ -109,7 +118,7 @@ def test_fused_extend_matches_oracle(q):
 
 
 @pytest.mark.parametrize("q", MOTIFS, ids=lambda q: q.name)
-def test_fused_step_bitexact_vs_jnp_step(q):
+def test_fused_step_bitexact_vs_jnp_step(q, extend_kernel_on):
     """The fused kernel middle must reproduce the jnp stage sequence
     bit-for-bit: identical output tuples AND identical work counters."""
     g = random_graph(40, 380, 5)
@@ -128,7 +137,7 @@ def test_fused_step_bitexact_vs_jnp_step(q):
     np.testing.assert_array_equal(a.tuples, b.tuples)
 
 
-def test_fused_level_branch_is_single_launch():
+def test_fused_level_branch_is_single_launch(extend_kernel_on):
     """Each extension-level branch of the dataflow step lowers to exactly
     one pallas_call: no proposal round-trips through HBM between stages."""
     q = Q.four_clique()
@@ -142,6 +151,32 @@ def test_fused_level_branch_is_single_launch():
     state = make_state(plan, cfg)
     n = count_pallas_calls(step, state, idx)
     assert n == len(plan.levels)  # one fused launch per level branch
+
+
+def test_default_level_branch_probes_with_member_kernel():
+    """On the default path (extend off it) each level branch intersects
+    through ONE multi-region member launch per binding."""
+    from repro.kernels import on_default_path
+    assert not on_default_path("extend") and on_default_path("member")
+    q = Q.four_clique()
+    g = random_graph(30, 250, 9)
+    plan = make_plan(q)
+    idx = build_indices(plan, {Q.EDGE: g.edges})
+    cfg = BigJoinConfig(batch=128, seed_chunk=64, mode="count",
+                        use_kernel=True)
+    from repro.core.bigjoin import make_state
+    step = build_step(plan, cfg)
+    n = count_pallas_calls(step, make_state(plan, cfg), idx)
+    assert n == sum(len(lv.bindings) for lv in plan.levels)
+
+
+def test_kernel_family_choice_is_static():
+    from repro.kernels import FAMILIES, OFF_DEFAULT_PATH, on_default_path
+    assert set(OFF_DEFAULT_PATH) == {"extend", "rank", "fold"}
+    assert [f for f in FAMILIES if on_default_path(f)] == ["member"]
+    assert all(OFF_DEFAULT_PATH.values())  # each records its reason
+    with pytest.raises(ValueError):
+        on_default_path("gather")
 
 
 # ---------------------------------------------------------------------------

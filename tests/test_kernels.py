@@ -25,7 +25,7 @@ except ModuleNotFoundError:  # container image may lack hypothesis
 from repro.kernels.flash_attention.flash_attention import _flash_call
 from repro.kernels.flash_attention.ops import mha
 from repro.kernels.flash_attention.ref import attention_ref
-from repro.kernels.intersect.ops import member
+from repro.kernels.intersect.ops import member, split_words
 from repro.kernels.intersect.ref import member_ref
 from repro.kernels.segment_ops.ops import segment_sum
 from repro.kernels.segment_ops.ref import segment_sum_ref
@@ -58,6 +58,46 @@ def test_intersect_sweep(n, dtype):
             jnp.asarray(qk), jnp.asarray(qv))
     np.testing.assert_array_equal(np.asarray(member(*args)),
                                   np.asarray(member_ref(*args)))
+
+
+@pytest.mark.parametrize("seed", range(4))
+def test_split_words_preserve_int64_order(seed):
+    """The kernel compares int64 keys as (high, low ^ sign-bit) int32 word
+    pairs: lexicographic order of the pairs must be the int64 order."""
+    rng = np.random.default_rng(seed)
+    a = rng.integers(-2**63, 2**63 - 1, 4000, dtype=np.int64)
+    a[:100] = a[100:200] ^ rng.integers(0, 2**32, 100)  # same high word
+    hi, lo = (np.asarray(w).astype(np.int64)
+              for w in split_words(jnp.asarray(a)))
+    order = np.lexsort((lo, hi))
+    np.testing.assert_array_equal(a[order], np.sort(a))
+    b = a[::-1]
+    bhi, blo = (np.asarray(w).astype(np.int64)
+                for w in split_words(jnp.asarray(b)))
+    np.testing.assert_array_equal((hi == bhi) & (lo == blo), a == b)
+
+
+@pytest.mark.parametrize("seed", range(3))
+def test_intersect_wide_int64_keys(seed):
+    """int64 keys whose words both vary — high words collide, low words
+    carry the sign bit — against the jnp oracle."""
+    rng = np.random.default_rng(40 + seed)
+    n = 3000
+    hi = rng.integers(0, 6, n).astype(np.int64)
+    lo = rng.integers(2**31 - 40, 2**31 + 40, n).astype(np.int64)
+    keys = (hi << 32) | lo
+    vals = rng.integers(0, 4, n).astype(np.int32)
+    kv = np.unique(np.stack([keys, vals.astype(np.int64)], 1), axis=0)
+    k, v = kv[:, 0], kv[:, 1].astype(np.int32)
+    B = 700
+    pick = rng.integers(0, k.size, B)
+    qk = k[pick] ^ rng.choice(np.array([0, 0, 1, 1 << 32, 1 << 31]), B)
+    qv = v[pick]
+    args = (jnp.asarray(k), jnp.asarray(v), jnp.asarray(np.int32(k.size)),
+            jnp.asarray(qk), jnp.asarray(qv))
+    got = np.asarray(member(*args))
+    np.testing.assert_array_equal(got, np.asarray(member_ref(*args)))
+    assert got.any() and not got.all()
 
 
 @settings(max_examples=25, deadline=None)

@@ -31,6 +31,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+from repro.compat import primitive_names
 from repro.core import csr
 from repro.core import delta as D
 from repro.core import query as Q
@@ -525,25 +526,7 @@ def test_composite_commit_fold_jaxpr_is_pure_device_compute():
     )(st.lb, st.lc_ins, st.lc_del, ui, ud)
     bad = {"pure_callback", "io_callback", "debug_callback", "callback",
            "infeed", "outfeed", "device_put"}
-
-    def _subjaxprs(v):
-        if isinstance(v, jax.core.ClosedJaxpr):
-            yield v.jaxpr
-        elif isinstance(v, jax.core.Jaxpr):
-            yield v
-        elif isinstance(v, (tuple, list)):
-            for x in v:
-                yield from _subjaxprs(x)
-
-    def walk(jaxpr, seen):
-        for eqn in jaxpr.eqns:
-            seen.add(eqn.primitive.name)
-            for v in eqn.params.values():
-                for sub in _subjaxprs(v):
-                    walk(sub, seen)
-
-    seen = set()
-    walk(closed.jaxpr, seen)
+    seen = primitive_names(closed)
     assert not (seen & bad), seen & bad
 
 

@@ -23,6 +23,7 @@ import pytest
 
 import jax
 
+from repro.compat import primitive_names
 from repro.core import csr
 from repro.core import delta as D
 from repro.core import query as Q
@@ -305,25 +306,7 @@ def test_commit_fold_jaxpr_is_pure_device_compute():
     )(reg.d_base, reg.d_cins, reg.d_cdel, reg.d_uins, reg.d_udel)
     bad = {"pure_callback", "io_callback", "debug_callback", "callback",
            "infeed", "outfeed", "device_put"}
-
-    def walk(jaxpr, seen):
-        for eqn in jaxpr.eqns:
-            seen.add(eqn.primitive.name)
-            for v in eqn.params.values():
-                for sub in _subjaxprs(v):
-                    walk(sub, seen)
-
-    def _subjaxprs(v):
-        if isinstance(v, jax.core.ClosedJaxpr):
-            yield v.jaxpr
-        elif isinstance(v, jax.core.Jaxpr):
-            yield v
-        elif isinstance(v, (tuple, list)):
-            for x in v:
-                yield from _subjaxprs(x)
-
-    seen = set()
-    walk(closed.jaxpr, seen)
+    seen = primitive_names(closed)
     assert not (seen & bad), seen & bad
 
 
@@ -496,3 +479,15 @@ def test_legacy_normalize_uses_packed_cache(monkeypatch):
         store._packed_live,
         np.sort(real(store.edges[:, 0], store.edges[:, 1])))
     np.testing.assert_array_equal(store.edges, cur)
+
+
+@pytest.mark.parametrize("cols,lo,hi", [(2, 0, 60), (2, -2**31, 2**31 - 1),
+                                        (3, 0, 9), (4, -3, 3)])
+def test_unique_rows_matches_np_unique(cols, lo, hi):
+    rng = np.random.default_rng(cols * 7 + hi % 97)
+    rows = rng.integers(lo, hi, (5000, cols), endpoint=True).astype(np.int32)
+    np.testing.assert_array_equal(csr.unique_rows(rows),
+                                  np.unique(rows, axis=0))
+    wide = rows.astype(np.int64) << 20
+    np.testing.assert_array_equal(csr.unique_rows(wide),
+                                  np.unique(wide, axis=0))
