@@ -27,7 +27,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core import compilestats
+from repro.core import compilestats, hostread
 from repro.core.dataflow_index import VersionedIndex
 from repro.core.plan import Plan
 from repro.errors import (CapacityOverflow, OVF_OUT, OVF_QUEUE, OVF_SEED)
@@ -423,18 +423,28 @@ class JoinResult:
     proposals: int
     intersections: int
     steps: int
+    host_reads: int = 0  # blocking device->host reads of the run
+    host_read_bytes: int = 0
 
 
 def run_bigjoin(plan: Plan, indices: Indices, seed: np.ndarray,
                 weights: Optional[np.ndarray] = None,
                 cfg: BigJoinConfig = BigJoinConfig()) -> JoinResult:
     """Host driver: feed seed chunks, drain the dataflow to completion."""
+    with jax.profiler.TraceAnnotation("dataflow.run"):
+        return _run_bigjoin(plan, indices, seed, weights, cfg)
+
+
+def _run_bigjoin(plan: Plan, indices: Indices, seed: np.ndarray,
+                 weights: Optional[np.ndarray], cfg: BigJoinConfig
+                 ) -> JoinResult:
     step, seed_step = _compiled_fns(plan, cfg)
     state = make_state(plan, cfg)
     seed = np.asarray(seed, np.int32).reshape(-1, plan.seed_width)
     if weights is None:
         weights = np.ones(seed.shape[0], np.int32)
     weights = np.asarray(weights, np.int32)
+    reads = hostread.Reads()
     S = cfg.seed_chunk
     nsteps = 0
     for lo in range(0, max(seed.shape[0], 1), S):
@@ -450,23 +460,29 @@ def run_bigjoin(plan: Plan, indices: Indices, seed: np.ndarray,
         state = seed_step(state, indices, jnp.asarray(chunk),
                           jnp.asarray(wchunk), jnp.asarray(vmask))
         while True:
-            sizes = [int(q.size) for q in state.queues]
+            sizes = [hostread.read("queues", reads, int, q.size)
+                     for q in state.queues]
             if not any(s > 0 for s in sizes):
                 break
             state = step(state, indices)
             nsteps += 1
-    mask = int(state.overflow)
+    mask = hostread.read("scalars", reads, int, state.overflow)
     if mask:
         raise CapacityOverflow(
             mask, where="local bigjoin",
             detail=f"batch={cfg.batch} out_capacity={cfg.out_capacity}")
     tuples = wts = None
     if cfg.mode == "collect":
-        n = int(state.out_n)
-        tuples = np.asarray(state.out_buf)[:n]
-        wts = np.asarray(state.out_weight)[:n]
-    return JoinResult(int(state.out_count), tuples, wts,
-                      int(state.proposals), int(state.intersections), nsteps)
+        n = hostread.read("scalars", reads, int, state.out_n)
+        tuples = hostread.read("collect", reads, np.asarray,
+                               state.out_buf)[:n]
+        wts = hostread.read("collect", reads, np.asarray,
+                            state.out_weight)[:n]
+    count, proposals, intersections = (
+        hostread.read("scalars", reads, int, x)
+        for x in (state.out_count, state.proposals, state.intersections))
+    return JoinResult(count, tuples, wts, proposals, intersections, nsteps,
+                      reads.host_reads, reads.host_read_bytes)
 
 
 def build_indices(plan: Plan, relations: Dict[str, np.ndarray],

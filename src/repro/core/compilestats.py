@@ -50,12 +50,6 @@ def record(name: str) -> None:
         _COUNTS[name] = _COUNTS.get(name, 0) + 1
 
 
-def counts() -> Dict[str, int]:
-    """Per-site compile-event counts (copy)."""
-    with _LOCK:
-        return dict(_COUNTS)
-
-
 def total() -> int:
     """Total compile events since process start (or :func:`reset`)."""
     with _LOCK:

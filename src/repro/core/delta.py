@@ -59,7 +59,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import faults
-from repro.core import compilestats, csr
+from repro.core import compilestats, csr, hostread
 from repro.core.bigjoin import (BigJoinConfig, Indices, JoinResult,
                                 run_bigjoin)
 from repro.core.capacity import Ratchet
@@ -207,10 +207,12 @@ def _maxn(n) -> int:
     return int(np.max(n)) if np.ndim(np.asarray(n)) else int(n)
 
 
-def _count_of(d: IndexData):
+def _count_of(d: IndexData, stats=None, site: str = "commit"):
     """Exact live count(s) of a device region: int single-host, [w] int64
-    vector sharded.  One scalar/vector pull — never the index arrays."""
-    n = np.asarray(d.n)
+    vector sharded.  One scalar/vector pull — never the index arrays; a
+    counted ``read.<site>`` when ``stats`` (the store's) is given."""
+    n = np.asarray(d.n) if stats is None else \
+        hostread.read(site, stats, np.asarray, d.n)
     return n.astype(np.int64) if n.ndim else int(n)
 
 
@@ -708,8 +710,9 @@ class _Regions:
             ratchet.capacity(rkey, ins.shape[0])
         qk, qv = _pad_probe(key, ins[:, self.ext_pos].astype(np.int32),
                             sent, cap=cap, mesh=self.mesh)
-        return bool(_any_member(self.d_cdel, qk, qv,
-                                sharded=bool(self.shard_w)))
+        return hostread.read("compact", self._store.stats, bool,
+                             _any_member(self.d_cdel, qk, qv,
+                                         sharded=bool(self.shard_w)))
 
     def versioned(self, version: str) -> VersionedIndex:
         if self.derived:
@@ -799,6 +802,10 @@ class StoreStats:
     replays: int = 0  # epoch dataflow re-runs after an escalation
     rollbacks: int = 0  # rollback() calls (faulted commits)
     escalation_compiles: int = 0  # compile events spent re-prewarming
+    # blocking device->host reads of normalize, commit and compaction
+    # (``hostread.read``), and the bytes they moved
+    host_reads: int = 0
+    host_read_bytes: int = 0
 
 
 @dataclasses.dataclass
@@ -1417,16 +1424,17 @@ class RegionStore:
         live relation state on device (one jitted probe per relation).
         Always returns the per-relation ``{rel: (ins, dels)}`` dict —
         :meth:`normalize` unwraps the edge sugar."""
-        faults.fire("store.normalize")
-        self.stats.normalize_calls += 1
-        out = {}
-        for rel, (rows, w) in prep.raw.items():
-            if not self.device_resident:
-                out[rel] = self._normalize_host(rel, rows, w)
-            else:
-                out[rel] = self._normalize_device(rel, *prep.rels[rel])
-        self._sync_compile_stats()
-        return out
+        with jax.profiler.TraceAnnotation("store.normalize"):
+            faults.fire("store.normalize")
+            self.stats.normalize_calls += 1
+            out = {}
+            for rel, (rows, w) in prep.raw.items():
+                if not self.device_resident:
+                    out[rel] = self._normalize_host(rel, rows, w)
+                else:
+                    out[rel] = self._normalize_device(rel, *prep.rels[rel])
+            self._sync_compile_stats()
+            return out
 
     def normalize(self, updates, weights=None):
         """Net out a batch against the live relation state.
@@ -1495,11 +1503,12 @@ class RegionStore:
             oih, oil, ni, odh, odl, nd = _normalize_core(
                 dh, dl, dw, st.lb, st.lc_ins, st.lc_del,
                 sharded=bool(self.shard_w))
-        ni, nd = int(ni), int(nd)
-        ins = _unpack_rows(np.asarray(oih)[:ni], np.asarray(oil)[:ni],
-                           st.arity)
-        dels = _unpack_rows(np.asarray(odh)[:nd], np.asarray(odl)[:nd],
-                            st.arity)
+        read = functools.partial(hostread.read, "normalize", self.stats)
+        ni, nd = read(int, ni), read(int, nd)
+        ins = _unpack_rows(read(np.asarray, oih)[:ni],
+                           read(np.asarray, oil)[:ni], st.arity)
+        dels = _unpack_rows(read(np.asarray, odh)[:nd],
+                            read(np.asarray, odl)[:nd], st.arity)
         return ins, dels
 
     def _normalize_host(self, rel: str, updates: np.ndarray,
@@ -1546,32 +1555,8 @@ class RegionStore:
             if (force or _total(nci) + _total(ncd) >
                     self.compact_ratio * max(_total(nb), 1)) and \
                     (_total(nci) or _total(ncd)):
-                new_nb = np.asarray(nb) - np.asarray(ncd) + np.asarray(nci)
-                out_cap = self.base_ratchet.capacity(("base", rel),
-                                                     _maxn(new_nb))
-                with _device_scope():
-                    st.lb = _compact_fold(st.lb, st.lc_ins, st.lc_del,
-                                          out_cap=out_cap,
-                                          sharded=bool(self.shard_w),
-                                          use_kernel=use_k)
-                zero = np.zeros(self.shard_w, np.int64) if self.shard_w \
-                    else 0
-                st.lc_ins = _empty_packed(self.shard_w, st.arity, self.mesh)
-                st.lc_del = _empty_packed(self.shard_w, st.arity, self.mesh)
-                st.n_live = [new_nb if self.shard_w else int(new_nb),
-                             zero, zero]
-                self.stats.live_compactions += 1
-                st.mirror = None
-                # the committed regions drained to zero: restart their
-                # rung ladder instead of pinning every future fold at the
-                # pre-compaction rung (which would cost O(threshold) per
-                # epoch).  The replayed rungs are already in the jit
-                # cache, so re-walking the ladder compiles nothing new.
-                self.ratchet.reset(("committed", rel))
-                # invariant audit: cdel ⊆ base and cins ∩ base = ∅ make the
-                # compacted size exact arithmetic — a mismatch means
-                # corruption
-                assert (np.asarray(_count_of(st.lb)) == new_nb).all()
+                with jax.profiler.TraceAnnotation("store.compact"):
+                    self._compact_live(rel, st, use_k)
         for reg in self.projections.values():
             if reg.derived:
                 continue  # rebuilt from the relation rows on demand
@@ -1580,28 +1565,61 @@ class RegionStore:
                     self.compact_ratio * max(_total(reg.n_base), 1)):
                 continue
             if committed:
-                new_n = np.asarray(reg.n_base) - np.asarray(reg.n_cdel) \
-                    + np.asarray(reg.n_cins)
-                out_cap = self.base_ratchet.capacity(("base", reg.rel),
-                                                     _maxn(new_n))
-                with _device_scope():
-                    reg.d_base = _compact_fold(
-                        reg.d_base, reg.d_cins, reg.d_cdel,
-                        out_cap=out_cap,
-                        sharded=bool(self.shard_w), use_kernel=use_k)
-                assert (np.asarray(_count_of(reg.d_base)) == new_n).all()
-                reg.n_base = _count_of(reg.d_base) if self.shard_w \
-                    else int(new_n)
-                self.ratchet.reset(("committed", reg.rel))
-                empty = np.zeros((0, reg.arity), np.int32)
-                reg.d_cins = reg._build(empty, kind="committed")
-                reg.d_cdel = reg._build(empty, kind="committed")
-                reg.n_cins = np.zeros(self.shard_w, np.int64) \
-                    if self.shard_w else 0
-                reg.n_cdel = np.zeros(self.shard_w, np.int64) \
-                    if self.shard_w else 0
-                self.stats.compactions += 1
-                reg._mirror.clear()
+                with jax.profiler.TraceAnnotation("store.compact"):
+                    self._compact_projection(reg, use_k)
+
+    def _compact_live(self, rel: str, st: _RelLive, use_k: bool):
+        """Fold one relation's committed live-set regions into its base."""
+        nb, nci, ncd = st.n_live
+        new_nb = np.asarray(nb) - np.asarray(ncd) + np.asarray(nci)
+        out_cap = self.base_ratchet.capacity(("base", rel), _maxn(new_nb))
+        with _device_scope():
+            st.lb = _compact_fold(st.lb, st.lc_ins, st.lc_del,
+                                  out_cap=out_cap,
+                                  sharded=bool(self.shard_w),
+                                  use_kernel=use_k)
+        zero = np.zeros(self.shard_w, np.int64) if self.shard_w else 0
+        st.lc_ins = _empty_packed(self.shard_w, st.arity, self.mesh)
+        st.lc_del = _empty_packed(self.shard_w, st.arity, self.mesh)
+        st.n_live = [new_nb if self.shard_w else int(new_nb), zero, zero]
+        self.stats.live_compactions += 1
+        st.mirror = None
+        # the committed regions drained to zero: restart their rung ladder
+        # instead of pinning every future fold at the pre-compaction rung
+        # (which would cost O(threshold) per epoch).  The replayed rungs
+        # are already in the jit cache, so re-walking the ladder compiles
+        # nothing new.
+        self.ratchet.reset(("committed", rel))
+        # invariant audit: cdel ⊆ base and cins ∩ base = ∅ make the
+        # compacted size exact arithmetic — a mismatch means corruption
+        assert (np.asarray(_count_of(st.lb, self.stats, "compact"))
+                == new_nb).all()
+
+    def _compact_projection(self, reg: _Regions, use_k: bool):
+        """Fold one projection's committed regions into its base."""
+        new_n = np.asarray(reg.n_base) - np.asarray(reg.n_cdel) \
+            + np.asarray(reg.n_cins)
+        out_cap = self.base_ratchet.capacity(("base", reg.rel),
+                                             _maxn(new_n))
+        with _device_scope():
+            reg.d_base = _compact_fold(
+                reg.d_base, reg.d_cins, reg.d_cdel,
+                out_cap=out_cap,
+                sharded=bool(self.shard_w), use_kernel=use_k)
+        assert (np.asarray(_count_of(reg.d_base, self.stats, "compact"))
+                == new_n).all()
+        reg.n_base = _count_of(reg.d_base, self.stats, "compact") \
+            if self.shard_w else int(new_n)
+        self.ratchet.reset(("committed", reg.rel))
+        empty = np.zeros((0, reg.arity), np.int32)
+        reg.d_cins = reg._build(empty, kind="committed")
+        reg.d_cdel = reg._build(empty, kind="committed")
+        reg.n_cins = np.zeros(self.shard_w, np.int64) \
+            if self.shard_w else 0
+        reg.n_cdel = np.zeros(self.shard_w, np.int64) \
+            if self.shard_w else 0
+        self.stats.compactions += 1
+        reg._mirror.clear()
 
     def _maybe_compact_host(self, force: bool = False):
         for reg in self.projections.values():
@@ -1671,8 +1689,10 @@ class RegionStore:
                                         cap=self._probe_cap(
                                             rel, r_ins.shape[0]),
                                         mesh=self.mesh)
-                    need = need or bool(_any_member(
-                        st.lc_del, qk, qv, sharded=bool(self.shard_w)))
+                    need = need or hostread.read(
+                        "compact", self.stats, bool,
+                        _any_member(st.lc_del, qk, qv,
+                                    sharded=bool(self.shard_w)))
                 if not need:
                     need = any(reg.probe_cdel(r_ins)
                                for reg in self.projections.values()
@@ -1714,6 +1734,10 @@ class RegionStore:
         bit-identical to the epoch boundary; :meth:`rollback` clears the
         staged batch.
         """
+        with jax.profiler.TraceAnnotation("store.commit"):
+            self._commit(ins, dels)
+
+    def _commit(self, ins, dels):
         if self._staged is None:
             # raw commit without begin_epoch: net the args against the live
             # set first (a live "insert" or absent "delete" must be a no-op,
@@ -1761,8 +1785,10 @@ class RegionStore:
                                mesh=self.mesh)
             self.ratchet.observe(("delta", rel), ld.key.shape[-1])
             nb, nci, ncd = st.n_live
-            need = max(_maxn(np.asarray(nci) + np.asarray(_count_of(li))),
-                       _maxn(np.asarray(ncd) + np.asarray(_count_of(ld))))
+            need = max(_maxn(np.asarray(nci)
+                             + np.asarray(_count_of(li, self.stats))),
+                       _maxn(np.asarray(ncd)
+                             + np.asarray(_count_of(ld, self.stats))))
             cc = self._committed_cap(rel, need)
             with _device_scope():
                 new_ci, new_cd = _commit_fold(
@@ -1770,7 +1796,8 @@ class RegionStore:
                     cins_cap=cc, cdel_cap=cc,
                     sharded=bool(self.shard_w), use_kernel=use_k)
             staged_rels.append((st, new_ci, new_cd,
-                                [nb, _count_of(new_ci), _count_of(new_cd)]))
+                                [nb, _count_of(new_ci, self.stats),
+                                 _count_of(new_cd, self.stats)]))
         # per-projection folds (vmapped over shards when distributed)
         staged_projs = []  # (reg, d_cins, d_cdel, empty_ins, empty_dels)
         derived_dirty = []
@@ -1786,9 +1813,9 @@ class RegionStore:
             faults.fire("store.commit.fold")
             need = max(
                 _maxn(np.asarray(reg.n_cins)
-                      + np.asarray(_count_of(reg.d_uins))),
+                      + np.asarray(_count_of(reg.d_uins, self.stats))),
                 _maxn(np.asarray(reg.n_cdel)
-                      + np.asarray(_count_of(reg.d_udel))))
+                      + np.asarray(_count_of(reg.d_udel, self.stats))))
             cc = self._committed_cap(reg.rel, need)
             with _device_scope():
                 d_cins, d_cdel = _commit_fold(
@@ -1807,8 +1834,8 @@ class RegionStore:
             reg._derived_cache.clear()
         for reg, d_cins, d_cdel, e_ins, e_dels in staged_projs:
             reg.d_cins, reg.d_cdel = d_cins, d_cdel
-            reg.n_cins = _count_of(d_cins)
-            reg.n_cdel = _count_of(d_cdel)
+            reg.n_cins = _count_of(d_cins, self.stats)
+            reg.n_cdel = _count_of(d_cdel, self.stats)
             reg.set_uncommitted(e_ins, e_dels)
             # commit never touches d_base: keep its mirror (compaction's
             # full clear is the one that must drop it)

@@ -36,9 +36,9 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro import compat, faults
-from repro.core import compilestats, csr
+from repro.core import compilestats, csr, hostread
 from repro.core import delta as _delta
-from repro.core.bigjoin import BigJoinConfig
+from repro.core.bigjoin import BigJoinConfig, JoinResult
 from repro.core.csr import AXIS  # noqa: F401  (re-exported)
 from repro.core.dataflow_index import VersionedIndex
 from repro.core.plan import Plan
@@ -696,24 +696,33 @@ def run_program(program, w: int, collect: bool, indices,
                 seed: np.ndarray, weights: np.ndarray, width: int = 2,
                 seed_floor: int = 0):
     """Deal the seed, launch one compiled program, unpack psum'd outputs."""
+    with jax.profiler.TraceAnnotation("dataflow.run"):
+        return _run_program(program, w, collect, indices, seed, weights,
+                            width, seed_floor)
+
+
+def _run_program(program, w, collect, indices, seed, weights, width,
+                 seed_floor):
     faults.fire("dist.program")
     chunks, seed_n, wchunks = deal_seed(seed, weights, w, width,
                                         floor=seed_floor)
     out = program(indices, jnp.asarray(chunks), jnp.asarray(seed_n),
                   jnp.asarray(wchunks))
-    mask = int(out[4])
+    reads = hostread.Reads()
+    mask = hostread.read("scalars", reads, int, out[4])
     if mask:
         raise CapacityOverflow(mask, where="distributed join",
                                detail=f"w={w} seed_floor={seed_floor}")
     tuples = wts = None
     if collect:
-        bufs, ws, ns = (np.asarray(out[7]), np.asarray(out[8]),
-                        np.asarray(out[9]))
+        bufs, ws, ns = (hostread.read("collect", reads, np.asarray, out[i])
+                        for i in (7, 8, 9))
         tuples = np.concatenate([bufs[i, :ns[i]] for i in range(w)])
         wts = np.concatenate([ws[i, :ns[i]] for i in range(w)])
-    from repro.core.bigjoin import JoinResult
-    return JoinResult(int(out[0]), tuples, wts, int(out[1]),
-                      int(out[2]), int(out[3]))
+    count, proposals, intersections, steps = (
+        hostread.read("scalars", reads, int, out[i]) for i in range(4))
+    return JoinResult(count, tuples, wts, proposals, intersections, steps,
+                      reads.host_reads, reads.host_read_bytes)
 
 
 @dataclasses.dataclass

@@ -54,6 +54,7 @@ import threading
 import time
 from typing import Callable, Dict, List, Optional, Tuple
 
+import jax
 import numpy as np
 
 from repro import faults
@@ -354,8 +355,9 @@ class SessionPool:
         tickets = [ticket for _b, ticket in group]
         t0 = time.perf_counter()
         try:
-            faults.fire("pool.prep")
-            prep = t.session.prepare(self._merge(group))
+            with jax.profiler.TraceAnnotation("pool.prep"):
+                faults.fire("pool.prep")
+                prep = t.session.prepare(self._merge(group))
         except Exception as e:  # bad batch: fail its tickets, keep serving
             self._fail_group(t, tickets, e)
             return False
@@ -472,32 +474,45 @@ class SessionPool:
         t0 = time.perf_counter()
         faults_before = len(faults.injected())
         logged = False
-        try:
-            faults.fire("pool.apply")
-            if t.durability is not None and t.durable:
-                epoch = self._wal_log(t, prep.raw)
-                logged = epoch is not None
-                if logged and self.on_logged is not None:
-                    self.on_logged(t.name, epoch)
-            res = t.session.update(prepared=prep)
-            if t.durability is not None and t.durable:
-                try:
-                    t.durability.maybe_snapshot()
-                except Exception:
-                    # the epoch is already durable in the WAL; a failed
-                    # snapshot only skips the cadence, never the commit
-                    with self._cv:
-                        t.stats.wal_errors += 1
-        except Exception as e:
-            if logged:
-                try:
-                    t.durability.wal.abort_last()
-                except WalError:
-                    pass
-            self._sync_robustness(t, faults_before)
-            self._fail_group(t, tickets, e)
-            return
-        ms = (time.perf_counter() - t0) * 1e3
+        reads = t.session.store.stats
+        reads0 = (reads.host_reads, reads.host_read_bytes)
+        with jax.profiler.TraceAnnotation("pool.apply") as span:
+            try:
+                faults.fire("pool.apply")
+                if t.durability is not None and t.durable:
+                    epoch = self._wal_log(t, prep.raw)
+                    logged = epoch is not None
+                    if logged and self.on_logged is not None:
+                        self.on_logged(t.name, epoch)
+                res = t.session.update(prepared=prep)
+                if t.durability is not None and t.durable:
+                    try:
+                        t.durability.maybe_snapshot()
+                    except Exception:
+                        # the epoch is already durable in the WAL; a
+                        # failed snapshot only skips the cadence, never
+                        # the commit
+                        with self._cv:
+                            t.stats.wal_errors += 1
+            except Exception as e:
+                if logged:
+                    try:
+                        t.durability.wal.abort_last()
+                    except WalError:
+                        pass
+                self._sync_robustness(t, faults_before)
+                self._fail_group(t, tickets, e)
+                return
+            ms = (time.perf_counter() - t0) * 1e3
+            # the epoch's dataflow runs and the store's reads around them
+            runs = [r for d in res.deltas.values() for r in d.per_dq]
+            counts = {
+                "level_steps": sum(r.steps for r in runs),
+                "host_reads": reads.host_reads - reads0[0]
+                + sum(r.host_reads for r in runs),
+                "host_read_bytes": reads.host_read_bytes - reads0[1]
+                + sum(r.host_read_bytes for r in runs)}
+            span.set_metadata(**counts)
         self._sync_robustness(t, faults_before)
         with self._cv:
             t.consecutive_failures = 0
@@ -506,6 +521,9 @@ class SessionPool:
             t.stats.coalesced_away += len(tickets) - 1
             t.stats.prep_ms.append(prep_ms)
             t.stats.apply_ms.append(ms)
+            t.stats.level_steps += counts["level_steps"]
+            t.stats.host_reads += counts["host_reads"]
+            t.stats.host_read_bytes += counts["host_read_bytes"]
             if t.durability is not None:
                 t.stats.snapshots = t.durability.snapshots
             self._inflight -= len(tickets)

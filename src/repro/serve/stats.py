@@ -38,6 +38,10 @@ class TenantStats:
     ``retired - epochs`` (= ``coalesced_away``) batches rode a shared
     commit.  ``prep_ms``/``apply_ms`` time the two pipeline stages
     (host pack vs device normalize+dataflow+commit) per epoch.
+    ``level_steps`` counts the dataflow level steps the host loop
+    dispatched, ``host_reads``/``host_read_bytes`` the blocking
+    device->host reads of the applied epochs and the bytes they moved
+    (``repro.core.hostread``).
     """
 
     name: str
@@ -65,6 +69,9 @@ class TenantStats:
     wal_degraded: bool = False
     quarantined: bool = False
     faults_injected: int = 0
+    level_steps: int = 0
+    host_reads: int = 0
+    host_read_bytes: int = 0
     prep_ms: List[float] = dataclasses.field(default_factory=list)
     apply_ms: List[float] = dataclasses.field(default_factory=list)
 
@@ -77,7 +84,8 @@ class TenantStats:
               "coalesced_away", "queue_depth", "snapshots", "replayed",
               "prewarm_compiles", "escalations", "replays",
               "escalation_compiles", "wal_errors", "wal_degraded",
-              "quarantined", "faults_injected")}
+              "quarantined", "faults_injected", "level_steps",
+              "host_reads", "host_read_bytes")}
         d["latency_ms"] = self.latency()
         d["prep_ms_p50"] = float(np.median(self.prep_ms)) \
             if self.prep_ms else 0.0
@@ -167,6 +175,8 @@ class ServeStats:
                 f"  {name}: {t.epochs} epochs / {t.retired} batches "
                 f"({t.coalesced_away} coalesced, {t.shed} shed, depth "
                 f"{t.queue_depth}); apply p50 {tl['p50']:.1f} ms p99 "
-                f"{tl['p99']:.1f} ms; {t.snapshots} snapshots, "
-                f"{t.replayed} replayed" + flags)
+                f"{tl['p99']:.1f} ms; {t.level_steps} level steps, "
+                f"{t.host_reads} host reads "
+                f"({t.host_read_bytes / 1e6:.1f} MB); {t.snapshots} "
+                f"snapshots, {t.replayed} replayed" + flags)
         return "\n".join(lines)
